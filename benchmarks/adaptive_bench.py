@@ -31,6 +31,7 @@ import repro.experiments as X
 import repro.workloads as W
 from repro.configs import get_config
 from repro.core.simulator import SimConfig
+from repro.compile_cache import use_compile_cache
 
 from .common import RESULTS_DIR, write_csv
 from .harness import BenchRun
@@ -162,6 +163,7 @@ def main(argv=None):
     ap.add_argument("--arch", default="qwen3_1_7b",
                     help="architecture for the mixed-tenant workload")
     args = ap.parse_args(argv)
+    use_compile_cache()
     params = SMOKE if args.smoke else (FULL if args.full else DEFAULT)
     bench_adaptive(params, arch=args.arch)
 

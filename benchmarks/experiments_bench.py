@@ -20,6 +20,7 @@ from functools import partial
 import repro.experiments as X
 import repro.workloads as W
 from repro.core.simulator import SimConfig
+from repro.compile_cache import use_compile_cache
 
 from .common import RESULTS_DIR
 
@@ -86,6 +87,7 @@ def main(argv=None):
     ap.add_argument("--chunk-size", type=int, default=None,
                     help="stream buckets in chunks of this many cells")
     args = ap.parse_args(argv)
+    use_compile_cache()
     bench(SMOKE if args.smoke else DEFAULT, chunk_size=args.chunk_size)
 
 

@@ -37,6 +37,7 @@ from repro.configs import get_config
 from repro.core import topology as T
 from repro.core.simulator import SimConfig, zero_load_latency
 from repro.faults import FaultError, sample_faults
+from repro.compile_cache import use_compile_cache
 
 from .common import RESULTS_DIR, write_csv
 
@@ -187,6 +188,7 @@ def main(argv=None):
     ap.add_argument("--arch", default="qwen3_1_7b",
                     help="architecture for the mixed-tenant workload")
     args = ap.parse_args(argv)
+    use_compile_cache()
     params = SMOKE if args.smoke else (FULL if args.full else DEFAULT)
     bench_faults(params, arch=args.arch)
 

@@ -44,6 +44,7 @@ from repro.obs.report import write_link_reports, write_window_reports
 from repro.obs.trace import (clear_trace, disable_tracing, enable_tracing,
                              get_spans, save_chrome_trace, trace)
 from repro.sweep.engine import SweepEngine
+from repro.compile_cache import use_compile_cache
 
 from .common import RESULTS_DIR
 from .harness import BenchRun
@@ -279,6 +280,7 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="tiny grid (CI-sized, well under a minute)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     bench_obs(SMOKE if args.smoke else DEFAULT)
 
 

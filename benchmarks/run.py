@@ -12,6 +12,8 @@ import argparse
 import sys
 import time
 
+from repro.compile_cache import use_compile_cache
+
 
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
@@ -24,6 +26,7 @@ def main(argv=None) -> None:
                     help="certify every benchmarked topology deadlock-"
                          "free (repro.analysis) before running figures")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.verify:
         # static preflight: a figure produced from an uncertified

@@ -30,6 +30,7 @@ import repro.experiments as X
 from repro.core import simulator as sim
 from repro.core.simulator import SimConfig, run_batch
 from repro.obs.metrics import metrics
+from repro.compile_cache import use_compile_cache
 
 from .common import RESULTS_DIR, write_csv
 from .harness import BenchRun
@@ -146,6 +147,7 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="tiny grid, finishes in well under 60 s")
     args = ap.parse_args(argv)
+    use_compile_cache()
     out = bench_speedup(smoke=args.smoke)
     for k, v in out.items():
         print(f"{k}: {v}")

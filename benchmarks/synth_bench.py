@@ -26,6 +26,7 @@ import time
 from repro.core.simulator import SimConfig
 from repro.experiments import io as xio
 from repro.synth import SearchConfig, run_search
+from repro.compile_cache import use_compile_cache
 
 from .common import RESULTS_DIR
 
@@ -43,6 +44,7 @@ def main() -> None:
     ap.add_argument("--out", default=os.path.join(RESULTS_DIR,
                                                   "synth_pareto.csv"))
     args = ap.parse_args()
+    use_compile_cache()
     import dataclasses
     cfg = dataclasses.replace(SMOKE if args.smoke else DEFAULT,
                               seed=args.seed)

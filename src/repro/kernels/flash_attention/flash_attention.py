@@ -47,10 +47,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int,
 
     def body(ki, carry):
         m_prev, l_prev, acc = carry
-        k = pl.load(k_ref, (pl.ds(ki * bk, bk), slice(None))
-                    ).astype(jnp.float32)                 # [bk, hd]
-        v = pl.load(v_ref, (pl.ds(ki * bk, bk), slice(None))
-                    ).astype(jnp.float32)
+        k = k_ref[pl.ds(ki * bk, bk), :].astype(jnp.float32)  # [bk, hd]
+        v = v_ref[pl.ds(ki * bk, bk), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)

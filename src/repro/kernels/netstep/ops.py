@@ -24,6 +24,5 @@ def is_available() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def netstep(op_slot, eligible, rr, *, block: int = 64):
-    return netstep_pallas(op_slot, eligible, rr, block=block,
-                          interpret=_interpret())
+def netstep(op_slot, eligible, rr):
+    return netstep_pallas(op_slot, eligible, rr, interpret=_interpret())

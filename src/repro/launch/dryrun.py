@@ -124,7 +124,6 @@ def build_lowered(arch: str, shape_name: str, multi_pod: bool,
 def _extract(compiled):
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
     coll = parse_collectives(compiled.as_text())
     return dict(
         flops=float(cost.get("flops", -1)),

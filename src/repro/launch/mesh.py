@@ -16,9 +16,16 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh():
     """A trivial 1x1 mesh for CPU smoke tests of the sharded code paths."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"), axis_types=_auto(2))
+
+
+def _auto(n: int) -> tuple:
+    """Auto axes: the sharded paths place arrays with
+    `with_sharding_constraint`, which Explicit axes (the make_mesh
+    default) reject."""
+    return (jax.sharding.AxisType.Auto,) * n

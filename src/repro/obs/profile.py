@@ -36,27 +36,17 @@ __all__ = [
 
 
 def _cost_fields(compiled) -> dict:
-    """Flatten `cost_analysis()` to {flops, bytes_accessed, transcendentals}.
-
-    jax 0.4.x returns a list with one properties-dict per computation
-    (keys like 'flops', 'bytes accessed'); newer versions return the
-    dict directly.  Sum across computations, None when absent.
-    """
+    """Read `cost_analysis()` (one properties dict, keys like 'flops',
+    'bytes accessed') into {flops, bytes_accessed, transcendentals};
+    None where a field is absent."""
     try:
-        ca = compiled.cost_analysis()
+        ca = compiled.cost_analysis() or {}
     except Exception:
-        return dict(flops=None, bytes_accessed=None, transcendentals=None)
-    if isinstance(ca, dict):
-        ca = [ca]
-    out = dict(flops=None, bytes_accessed=None, transcendentals=None)
+        ca = {}
     names = dict(flops="flops", bytes_accessed="bytes accessed",
                  transcendentals="transcendentals")
-    for props in ca or []:
-        for field, key in names.items():
-            v = props.get(key)
-            if v is not None:
-                out[field] = (out[field] or 0.0) + float(v)
-    return out
+    return {field: None if ca.get(key) is None else float(ca[key])
+            for field, key in names.items()}
 
 
 def _memory_fields(compiled) -> dict:

@@ -12,6 +12,7 @@ from repro.kernels.flash_attention.flash_attention import \
     flash_attention_bhsd
 from repro.kernels.ssd_scan.ssd_scan import ssd_scan_pallas
 from repro.kernels.ssd_scan.ref import ssd_ref, ssd_naive
+from repro.core.simulator import _alloc_jnp
 from repro.kernels.netstep.netstep import netstep_pallas
 from repro.kernels.netstep.ref import netstep_ref
 from repro.models.ssm import ssd_chunked_core
@@ -132,15 +133,22 @@ def _alloc_inputs(rng, n, pi, v):
     return jnp.asarray(op_slot), jnp.asarray(eligible)
 
 
-@pytest.mark.parametrize("n,pi,v", [(16, 5, 4), (100, 7, 4), (64, 31, 2)])
+@pytest.mark.parametrize("n,pi,v", [(16, 5, 4), (100, 7, 4), (64, 31, 2),
+                                    (37, 31, 4)])
 def test_netstep_matches_ref(n, pi, v):
+    """Bitwise equal to the oracle and to the simulator's own jnp
+    allocator, for one shared counter and for split (vc, port) ones."""
     rng = np.random.default_rng(4)
     op_slot, eligible = _alloc_inputs(rng, n, pi, v)
-    for rr in (0, 3, 11):
+    for rr in (0, 3, 11, (1, 29), (3, 0)):
         got = netstep_pallas(op_slot, eligible, rr, interpret=True)
         want = netstep_ref(op_slot, eligible, rr)
-        for g, w in zip(got, want):
+        rr_vc, rr_port = rr if isinstance(rr, tuple) else (rr, rr)
+        jnp_alloc = _alloc_jnp(op_slot, eligible, rr_vc, rr_port)
+        for g, w, j in zip(got, want, jnp_alloc):
+            assert g.dtype == w.dtype
             np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(j))
 
 
 @given(seed=st.integers(0, 10_000))
