@@ -58,24 +58,19 @@ def require_tpu():
     return dev
 
 
-class _CompileCounter:
-    """Counts XLA backend compiles (JAX's own monitoring event)."""
-
-    def __init__(self):
-        self.n = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, _secs, **_kw):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.n += 1
+def _xla_compiles() -> int:
+    """XLA backend compiles so far (the program's compile counters)."""
+    from repro.obs.metrics import metrics
+    return sum(v["count"] for k, v in metrics.snapshot().items()
+               if k.startswith("jit.compile_s:"))
 
 
-def _run(exp, counter):
+def _run(exp):
     """Run an Experiment; returns (frame, seconds, runner compiles, XLA
     compiles).  run_batch blocks on the device before returning."""
     import repro.experiments as X
     from repro.obs.metrics import cache_counters
-    misses0, xla0 = cache_counters()["cache.runner.misses"], counter.n
+    misses0, xla0 = cache_counters()["cache.runner.misses"], _xla_compiles()
     t0 = time.perf_counter()
     frame = X.run(exp)
     dt = time.perf_counter() - t0
@@ -84,7 +79,7 @@ def _run(exp, counter):
     if bad:
         raise RuntimeError(f"{exp.name}: cells not ok: {bad}")
     return (frame, dt, cache_counters()["cache.runner.misses"] - misses0,
-            counter.n - xla0)
+            _xla_compiles() - xla0)
 
 
 def _compiled_text(exp, topology: str) -> str:
@@ -151,7 +146,6 @@ def smoke(dev, cfg, n_a: int = 64, n_b: int = 256) -> list[str]:
     was measured, and return the failed checks."""
     import repro.experiments as X
     from repro.core import topology as T
-    counter = _CompileCounter()
     grid = lambda name, topos, n, subs, c: X.Experiment.grid(
         topos, [n], substrates=subs, rates=X.SaturationGrid(N_RATES),
         cfg=c, name=name)
@@ -163,8 +157,8 @@ def smoke(dev, cfg, n_a: int = 64, n_b: int = 256) -> list[str]:
 
     errs, frames = [], {}
     for exp in (exp_a, exp_b):
-        frame, cold, runners, xla = _run(exp, counter)
-        _, warm, wr, wx = _run(exp, counter)
+        frame, cold, runners, xla = _run(exp)
+        _, warm, wr, wx = _run(exp)
         frames[exp.name] = frame
         print(f"{exp.name}: {len(frame.rows)} cells, cold {cold:.3f} s "
               f"({runners} runner compiles, {xla} XLA compiles), "
@@ -176,7 +170,7 @@ def smoke(dev, cfg, n_a: int = 64, n_b: int = 256) -> list[str]:
         else:
             errs.append(f"{exp.name}: no tpu_custom_call in the compiled "
                         f"{topology} runner")
-    ref, cold, runners, xla = _run(exp_ref, counter)
+    ref, cold, runners, xla = _run(exp_ref)
     print(f"{exp_ref.name}: cold {cold:.3f} s ({runners} runner compiles, "
           f"{xla} XLA compiles)")
     errs += check_bitwise(frames[exp_a.name], ref)
@@ -189,7 +183,7 @@ def smoke(dev, cfg, n_a: int = 64, n_b: int = 256) -> list[str]:
                       f"{r['analytic_saturation']:.4f}")
     stats = dev.memory_stats() or {}
     print(f"peak_bytes_in_use: {stats.get('peak_bytes_in_use', 'n/a')}")
-    print(f"XLA compiles in total: {counter.n}")
+    print(f"XLA compiles in total: {_xla_compiles()}")
     return errs
 
 

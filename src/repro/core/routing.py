@@ -27,11 +27,13 @@ from __future__ import annotations
 import dataclasses
 import functools
 import os
+import time
 
 import numpy as np
 import scipy.sparse as sp
 import scipy.sparse.csgraph as csgraph
 
+from repro.obs.metrics import metrics
 from repro.obs.trace import trace as _span
 
 from .topology import Topology, build
@@ -391,7 +393,9 @@ def routing_for(topo: Topology, certify: bool = False) -> Routing:
     _ROUTING_CACHE_STATS["misses"] += 1
     with _span("routing.build", cat="routing", topology=topo.name,
                n=topo.n, substrate=topo.substrate):
+        t0 = time.perf_counter()
         r = build_routing(topo)
+        metrics.observe("routing.build_s", time.perf_counter() - t0)
     if certify:
         r.cert = _certify(r)
     _ROUTING_CACHE[key] = r

@@ -590,180 +590,189 @@ def _make_batch_runner(nm: int, pm: int, cm: int, dm: int,
             [a.ch_depth, jnp.ones((1,), jnp.int32)])        # [C+1]
 
         # ---- 1. link deliveries -> input buffers ----------------------
-        arr_dst = state.link_dst[:C, slot]           # [C]
-        arr_ok = arr_dst >= 0
-        arr_vc = state.link_vc[:C, slot]
-        pos = (state.head[a.ch_dst, a.ch_in_port, arr_vc] +
-               state.cnt[a.ch_dst, a.ch_in_port, arr_vc]) % B
-        pos_w = jnp.where(arr_ok, pos, B)            # B = sacrificial slot
-        buf_dst = state.buf_dst.at[a.ch_dst, a.ch_in_port, arr_vc,
-                                   pos_w].set(arr_dst)
-        buf_t = state.buf_t.at[a.ch_dst, a.ch_in_port, arr_vc,
-                               pos_w].set(state.link_t[:C, slot])
-        cnt = state.cnt.at[a.ch_dst, a.ch_in_port, arr_vc].add(
-            arr_ok.astype(jnp.int32))
-        link_dst = state.link_dst.at[:, slot].set(-1)
+        with jax.named_scope("step_arrivals"):
+            arr_dst = state.link_dst[:C, slot]           # [C]
+            arr_ok = arr_dst >= 0
+            arr_vc = state.link_vc[:C, slot]
+            pos = (state.head[a.ch_dst, a.ch_in_port, arr_vc] +
+                   state.cnt[a.ch_dst, a.ch_in_port, arr_vc]) % B
+            pos_w = jnp.where(arr_ok, pos, B)            # B = sacrificial slot
+            buf_dst = state.buf_dst.at[a.ch_dst, a.ch_in_port, arr_vc,
+                                       pos_w].set(arr_dst)
+            buf_t = state.buf_t.at[a.ch_dst, a.ch_in_port, arr_vc,
+                                   pos_w].set(state.link_t[:C, slot])
+            cnt = state.cnt.at[a.ch_dst, a.ch_in_port, arr_vc].add(
+                arr_ok.astype(jnp.int32))
+            link_dst = state.link_dst.at[:, slot].set(-1)
 
         # ---- 2. credit returns ----------------------------------------
-        credits = state.credits.at[a.ch_src, a.ch_out_port].add(
-            state.credit_pipe[:C, slot])
-        credit_pipe = state.credit_pipe.at[:, slot].set(0)
+        with jax.named_scope("step_credits"):
+            credits = state.credits.at[a.ch_src, a.ch_out_port].add(
+                state.credit_pipe[:C, slot])
+            credit_pipe = state.credit_pipe.at[:, slot].set(0)
 
         # ---- 3. injection ----------------------------------------------
-        if kmax:
-            # phase pointer: replay the schedule cyclically and count the
-            # phase ends already passed (padded rows end at 2^30 — inert)
-            t_eff = t % sch.total
-            ph = jnp.sum((sch.end <= t_eff).astype(jnp.int32))
-            in_on = ((t_eff - sch.start[ph]) % sch.period[ph]) < sch.on[ph]
-            rate_eff = rate * jnp.where(in_on, sch.gain_on[ph],
-                                        jnp.float32(0.0))
-            inj_w, cum = sch.inj_w[ph], sch.cum[ph]
-        else:
-            rate_eff, inj_w, cum = rate, a.inj_weight, a.traffic_cum
-        u_inj = _bits_to_unit(_node_bits(cfg.seed, t, node_r, 0))
-        want = u_inj < rate_eff * inj_w
-        u_dst = _bits_to_unit(_node_bits(cfg.seed, t, node_r, 1))
-        dsts = jnp.sum(cum < u_dst[:, None], axis=1)
-        dsts = jnp.clip(dsts, 0, N - 1).astype(jnp.int32)
-        vcs_inj = (_node_bits(cfg.seed, t, node_r, 2) % V).astype(jnp.int32)
-        want &= dsts != node_r
-        space = cnt[node_r, P, vcs_inj] < B
-        do_inj = want & space
-        posi = (state.head[node_r, P, vcs_inj] + cnt[node_r, P, vcs_inj]) % B
-        posi_w = jnp.where(do_inj, posi, B)
-        buf_dst = buf_dst.at[node_r, P, vcs_inj, posi_w].set(dsts)
-        buf_t = buf_t.at[node_r, P, vcs_inj, posi_w].set(t)
-        cnt = cnt.at[node_r, P, vcs_inj].add(do_inj.astype(jnp.int32))
-        m32 = measuring.astype(jnp.int32)
-        offered = state.offered + m32 * jnp.sum(want.astype(jnp.int32))
-        accepted = state.accepted + m32 * jnp.sum(do_inj.astype(jnp.int32))
+        with jax.named_scope("step_inject"):
+            if kmax:
+                # phase pointer: replay the schedule cyclically and count the
+                # phase ends already passed (padded rows end at 2^30 — inert)
+                t_eff = t % sch.total
+                ph = jnp.sum((sch.end <= t_eff).astype(jnp.int32))
+                in_on = ((t_eff - sch.start[ph]) % sch.period[ph]) < sch.on[ph]
+                rate_eff = rate * jnp.where(in_on, sch.gain_on[ph],
+                                            jnp.float32(0.0))
+                inj_w, cum = sch.inj_w[ph], sch.cum[ph]
+            else:
+                rate_eff, inj_w, cum = rate, a.inj_weight, a.traffic_cum
+            u_inj = _bits_to_unit(_node_bits(cfg.seed, t, node_r, 0))
+            want = u_inj < rate_eff * inj_w
+            u_dst = _bits_to_unit(_node_bits(cfg.seed, t, node_r, 1))
+            dsts = jnp.sum(cum < u_dst[:, None], axis=1)
+            dsts = jnp.clip(dsts, 0, N - 1).astype(jnp.int32)
+            vcs_inj = (_node_bits(cfg.seed, t, node_r, 2)
+                       % V).astype(jnp.int32)
+            want &= dsts != node_r
+            space = cnt[node_r, P, vcs_inj] < B
+            do_inj = want & space
+            posi = (state.head[node_r, P, vcs_inj]
+                    + cnt[node_r, P, vcs_inj]) % B
+            posi_w = jnp.where(do_inj, posi, B)
+            buf_dst = buf_dst.at[node_r, P, vcs_inj, posi_w].set(dsts)
+            buf_t = buf_t.at[node_r, P, vcs_inj, posi_w].set(t)
+            cnt = cnt.at[node_r, P, vcs_inj].add(do_inj.astype(jnp.int32))
+            m32 = measuring.astype(jnp.int32)
+            offered = state.offered + m32 * jnp.sum(want.astype(jnp.int32))
+            accepted = state.accepted + m32 * jnp.sum(do_inj.astype(jnp.int32))
 
         # ---- 4. route + allocate ---------------------------------------
-        cnt_obs = cnt            # occupancy snapshot (flight recorder):
-        #                          post-arrival, post-injection, pre-pop
-        head_dst = jnp.take_along_axis(
-            buf_dst, state.head[..., None], axis=3)[..., 0]
-        head_t = jnp.take_along_axis(
-            buf_t, state.head[..., None], axis=3)[..., 0]
-        cred_pad = jnp.concatenate(
-            [credits, jnp.full((N, 1, V), INF, jnp.int32)], axis=1)
-        if adaptive:
-            op_slot, eligible, starved, dvc = _route_lookup_adaptive(
-                a.table, a.prod, cred_pad, head_dst, cnt, N, P, V)
-        else:
-            op_slot, eligible, starved = _route_lookup(
-                a.table, cred_pad, head_dst, cnt, N, P, V)
-        rr_vc = state.rr % V
-        rr_port = state.rr % a.pi
-        win_mask, vc_choice, out_req = alloc_fn(op_slot, eligible,
-                                                rr_vc, rr_port)
-        port_wins = jnp.any(win_mask, axis=2)      # [N, PI]
+        with jax.named_scope("step_route"):
+            cnt_obs = cnt            # occupancy snapshot (flight recorder):
+            #                          post-arrival, post-injection, pre-pop
+            head_dst = jnp.take_along_axis(
+                buf_dst, state.head[..., None], axis=3)[..., 0]
+            head_t = jnp.take_along_axis(
+                buf_t, state.head[..., None], axis=3)[..., 0]
+            cred_pad = jnp.concatenate(
+                [credits, jnp.full((N, 1, V), INF, jnp.int32)], axis=1)
+            if adaptive:
+                op_slot, eligible, starved, dvc = _route_lookup_adaptive(
+                    a.table, a.prod, cred_pad, head_dst, cnt, N, P, V)
+            else:
+                op_slot, eligible, starved = _route_lookup(
+                    a.table, cred_pad, head_dst, cnt, N, P, V)
+        with jax.named_scope("step_alloc"):
+            rr_vc = state.rr % V
+            rr_port = state.rr % a.pi
+            win_mask, vc_choice, out_req = alloc_fn(op_slot, eligible,
+                                                    rr_vc, rr_port)
+            port_wins = jnp.any(win_mask, axis=2)      # [N, PI]
 
         # ---- 5. winners: pop, move, credit ------------------------------
-        # wvc is the *source* VC lane popped at (node, in-port); w_dvc is
-        # the *downstream* VC lane the flit occupies after the hop.  The
-        # static path keeps them equal (bitwise-identical jaxpr); the
-        # adaptive path redirects to the class chosen by the route
-        # lookup, so the upstream credit return (freeing the popped
-        # lane) stays on wvc while the link VC tag and the downstream
-        # credit decrement move to w_dvc.
-        wvc = vc_choice
-        w_dvc = dvc[nn, pp, wvc] if adaptive else wvc
-        w_dst = head_dst[nn, pp, wvc]
-        w_t = head_t[nn, pp, wvc]
-        head = (state.head.at[nn, pp, wvc]
-                .add(port_wins.astype(jnp.int32))) % B
-        cnt = cnt.at[nn, pp, wvc].add(-port_wins.astype(jnp.int32))
+        with jax.named_scope("step_move"):
+            # wvc is the *source* VC lane popped at (node, in-port); w_dvc is
+            # the *downstream* VC lane the flit occupies after the hop.  The
+            # static path keeps them equal (bitwise-identical jaxpr); the
+            # adaptive path redirects to the class chosen by the route
+            # lookup, so the upstream credit return (freeing the popped
+            # lane) stays on wvc while the link VC tag and the downstream
+            # credit decrement move to w_dvc.
+            wvc = vc_choice
+            w_dvc = dvc[nn, pp, wvc] if adaptive else wvc
+            w_dst = head_dst[nn, pp, wvc]
+            w_t = head_t[nn, pp, wvc]
+            head = (state.head.at[nn, pp, wvc]
+                    .add(port_wins.astype(jnp.int32))) % B
+            cnt = cnt.at[nn, pp, wvc].add(-port_wins.astype(jnp.int32))
 
-        # upstream credit return for real input ports
-        up_ch = a.in_ch[nn, jnp.clip(pp, 0, P - 1)]  # [N, PI]
-        has_up = (pp < P) & (up_ch >= 0) & port_wins
-        up_ch_s = jnp.maximum(up_ch, 0)
-        ret_slot = (t + ch_depth_pad[up_ch_s]) % D
-        credit_pipe = credit_pipe.at[up_ch_s, ret_slot, wvc].add(
-            has_up.astype(jnp.int32))
+            # upstream credit return for real input ports
+            up_ch = a.in_ch[nn, jnp.clip(pp, 0, P - 1)]  # [N, PI]
+            has_up = (pp < P) & (up_ch >= 0) & port_wins
+            up_ch_s = jnp.maximum(up_ch, 0)
+            ret_slot = (t + ch_depth_pad[up_ch_s]) % D
+            credit_pipe = credit_pipe.at[up_ch_s, ret_slot, wvc].add(
+                has_up.astype(jnp.int32))
 
-        # ejection vs traversal
-        eject = port_wins & (out_req == P)
-        traverse = port_wins & (out_req >= 0) & (out_req < P)
-        ej32 = jnp.sum(eject.astype(jnp.int32))
-        lat_row = jnp.sum(jnp.where(eject, t - w_t, 0), axis=1)
-        delivered = state.delivered + m32 * ej32
-        lat_node = state.lat_node + m32 * lat_row
-        ph_upd = {}
-        if kmax:
-            ph_upd = dict(
-                delivered_ph=state.delivered_ph.at[ph].add(m32 * ej32),
-                offered_ph=state.offered_ph.at[ph].add(
-                    m32 * jnp.sum(want.astype(jnp.int32))),
-                accepted_ph=state.accepted_ph.at[ph].add(
-                    m32 * jnp.sum(do_inj.astype(jnp.int32))),
-                lat_ph=state.lat_ph.at[ph].add(m32 * lat_row))
+            # ejection vs traversal
+            eject = port_wins & (out_req == P)
+            traverse = port_wins & (out_req >= 0) & (out_req < P)
+            ej32 = jnp.sum(eject.astype(jnp.int32))
+            lat_row = jnp.sum(jnp.where(eject, t - w_t, 0), axis=1)
+            delivered = state.delivered + m32 * ej32
+            lat_node = state.lat_node + m32 * lat_row
+            ph_upd = {}
+            if kmax:
+                ph_upd = dict(
+                    delivered_ph=state.delivered_ph.at[ph].add(m32 * ej32),
+                    offered_ph=state.offered_ph.at[ph].add(
+                        m32 * jnp.sum(want.astype(jnp.int32))),
+                    accepted_ph=state.accepted_ph.at[ph].add(
+                        m32 * jnp.sum(do_inj.astype(jnp.int32))),
+                    lat_ph=state.lat_ph.at[ph].add(m32 * lat_row))
 
-        out_c = a.out_ch[nn, jnp.clip(out_req, 0, P - 1)]
-        oc_w = jnp.where(traverse, out_c, C)       # C = sacrificial row
-        wslot = (t + ch_depth_pad[oc_w]) % D
-        link_dst = link_dst.at[oc_w, wslot].set(w_dst)
-        link_t = state.link_t.at[oc_w, wslot].set(w_t)
-        link_vc = state.link_vc.at[oc_w, wslot].set(w_dvc)
-        credits = credits.at[nn, jnp.clip(out_req, 0, P - 1), w_dvc].add(
-            -traverse.astype(jnp.int32))
+            out_c = a.out_ch[nn, jnp.clip(out_req, 0, P - 1)]
+            oc_w = jnp.where(traverse, out_c, C)       # C = sacrificial row
+            wslot = (t + ch_depth_pad[oc_w]) % D
+            link_dst = link_dst.at[oc_w, wslot].set(w_dst)
+            link_t = state.link_t.at[oc_w, wslot].set(w_t)
+            link_vc = state.link_vc.at[oc_w, wslot].set(w_dvc)
+            credits = credits.at[nn, jnp.clip(out_req, 0, P - 1), w_dvc].add(
+                -traverse.astype(jnp.int32))
 
         # ---- 6. flight recorder (telemetry mode only; DESIGN.md §13) ---
-        # Pure observers: every update is an int scatter-add onto a
-        # dedicated counter tensor, weighted by masks the step already
-        # computed, with non-contributing lanes routed to the sacrificial
-        # row C (or weighted 0) — so real counters are untouched and the
-        # per-spec slices stay padding-invariant.
-        tel_upd = {}
-        if cfg.telemetry:
-            # channel utilization: one traversal per (channel, cycle)
-            tel_busy = state.tel_busy.at[oc_w].add(
-                m32 * traverse.astype(jnp.int32))
-            # credit starvation, attributed to the requested out channel
-            st_ch = a.out_ch[jnp.arange(N)[:, None, None],
-                             jnp.clip(op_slot, 0, P - 1)]  # [N, PI, V]
-            st_ch_w = jnp.where(starved, st_ch, C)
-            tel_stall = state.tel_stall.at[st_ch_w].add(
-                m32 * starved.astype(jnp.int32))
-            # per-VC occupancy of each channel's downstream input buffer
-            occ = cnt_obs[a.ch_dst, a.ch_in_port]          # [C, V]
-            tel_occ = state.tel_occ.at[jnp.arange(C)].add(m32 * occ)
-            # injection/ejection conservation counters (sum == accepted /
-            # delivered exactly — the reconciliation tests rely on this)
-            tel_inj = state.tel_inj + m32 * do_inj.astype(jnp.int32)
-            tel_eject = state.tel_eject + m32 * jnp.sum(
-                eject.astype(jnp.int32), axis=1)
-            # coarse latency histogram: bin h counts lat in [2^(h-1), 2^h)
-            edges = jnp.int32(2) ** jnp.arange(LAT_HIST_BINS - 1)
-            lat = t - w_t                                  # [N, PI]
-            hbin = jnp.sum((lat[..., None] >= edges).astype(jnp.int32),
-                           axis=-1)
-            tel_hist = state.tel_hist.at[hbin].add(
-                m32 * eject.astype(jnp.int32))
-            tel_upd = dict(tel_busy=tel_busy, tel_stall=tel_stall,
-                           tel_occ=tel_occ, tel_inj=tel_inj,
-                           tel_eject=tel_eject, tel_hist=tel_hist)
-            if W:
-                # time-windowed bins (DESIGN.md §16): the SAME masks and
-                # weights as the aggregates above, scattered once more
-                # with a leading window index — so summing the window
-                # axis reconciles to the aggregates bitwise (int adds,
-                # every measured cycle lands in exactly one window;
-                # pre-warmup cycles clip to window 0 with weight 0).
-                w = jnp.clip(((t - cfg.warmup) * W) // meas, 0, W - 1)
-                tel_upd.update(
-                    tel_busy_w=state.tel_busy_w.at[w, oc_w].add(
-                        m32 * traverse.astype(jnp.int32)),
-                    tel_stall_w=state.tel_stall_w.at[w, st_ch_w].add(
-                        m32 * starved.astype(jnp.int32)),
-                    tel_occ_w=state.tel_occ_w.at[w, jnp.arange(C)].add(
-                        m32 * occ),
-                    tel_inj_w=state.tel_inj_w.at[w].add(
-                        m32 * do_inj.astype(jnp.int32)),
-                    tel_eject_w=state.tel_eject_w.at[w].add(
-                        m32 * jnp.sum(eject.astype(jnp.int32), axis=1)))
+        with jax.named_scope("step_flight"):
+            # Pure observers: every update is an int scatter-add onto a
+            # dedicated counter tensor, weighted by masks the step already
+            # computed, with non-contributing lanes routed to the sacrificial
+            # row C (or weighted 0) — so real counters are untouched and the
+            # per-spec slices stay padding-invariant.
+            tel_upd = {}
+            if cfg.telemetry:
+                # channel utilization: one traversal per (channel, cycle)
+                tel_busy = state.tel_busy.at[oc_w].add(
+                    m32 * traverse.astype(jnp.int32))
+                # credit starvation, attributed to the requested out channel
+                st_ch = a.out_ch[jnp.arange(N)[:, None, None],
+                                 jnp.clip(op_slot, 0, P - 1)]  # [N, PI, V]
+                st_ch_w = jnp.where(starved, st_ch, C)
+                tel_stall = state.tel_stall.at[st_ch_w].add(
+                    m32 * starved.astype(jnp.int32))
+                # per-VC occupancy of each channel's downstream input buffer
+                occ = cnt_obs[a.ch_dst, a.ch_in_port]          # [C, V]
+                tel_occ = state.tel_occ.at[jnp.arange(C)].add(m32 * occ)
+                # injection/ejection conservation counters (sum == accepted /
+                # delivered exactly — the reconciliation tests rely on this)
+                tel_inj = state.tel_inj + m32 * do_inj.astype(jnp.int32)
+                tel_eject = state.tel_eject + m32 * jnp.sum(
+                    eject.astype(jnp.int32), axis=1)
+                # coarse latency histogram: bin h counts lat in [2^(h-1), 2^h)
+                edges = jnp.int32(2) ** jnp.arange(LAT_HIST_BINS - 1)
+                lat = t - w_t                                  # [N, PI]
+                hbin = jnp.sum((lat[..., None] >= edges).astype(jnp.int32),
+                               axis=-1)
+                tel_hist = state.tel_hist.at[hbin].add(
+                    m32 * eject.astype(jnp.int32))
+                tel_upd = dict(tel_busy=tel_busy, tel_stall=tel_stall,
+                               tel_occ=tel_occ, tel_inj=tel_inj,
+                               tel_eject=tel_eject, tel_hist=tel_hist)
+                if W:
+                    # time-windowed bins (DESIGN.md §16): the SAME masks and
+                    # weights as the aggregates above, scattered once more
+                    # with a leading window index — so summing the window
+                    # axis reconciles to the aggregates bitwise (int adds,
+                    # every measured cycle lands in exactly one window;
+                    # pre-warmup cycles clip to window 0 with weight 0).
+                    w = jnp.clip(((t - cfg.warmup) * W) // meas, 0, W - 1)
+                    tel_upd.update(
+                        tel_busy_w=state.tel_busy_w.at[w, oc_w].add(
+                            m32 * traverse.astype(jnp.int32)),
+                        tel_stall_w=state.tel_stall_w.at[w, st_ch_w].add(
+                            m32 * starved.astype(jnp.int32)),
+                        tel_occ_w=state.tel_occ_w.at[w, jnp.arange(C)].add(
+                            m32 * occ),
+                        tel_inj_w=state.tel_inj_w.at[w].add(
+                            m32 * do_inj.astype(jnp.int32)),
+                        tel_eject_w=state.tel_eject_w.at[w].add(
+                            m32 * jnp.sum(eject.astype(jnp.int32), axis=1)))
 
         return SimState(
             buf_dst=buf_dst, buf_t=buf_t, head=head, cnt=cnt,

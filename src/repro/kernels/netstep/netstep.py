@@ -126,5 +126,6 @@ def netstep_pallas(op_slot, eligible, rr, *, interpret: bool = False):
             jax.ShapeDtypeStruct((np_, pi), jnp.int32),
         ],
         interpret=interpret,
+        name="netstep",
     )(op_slot, eligible, rr_tile)
     return win[:n] != 0, vc[:n], req[:n]

@@ -17,11 +17,21 @@ telemetry (`SimConfig(telemetry_windows=W)` -> `window_rows` /
 profiling per compiled runner (`obs.profile`), and the structured
 benchmark harness + regression gate (`obs.bench`,
 `python -m repro.obs.bench compare`).
+
+Always on, unlike the rest: the set-up counters — JAX's compile
+pipeline per function (`jit.trace_s:<fun>`, `jit.lower_s:<fun>`,
+`jit.compile_s:<fun>`, `jit.cache_load_s`, `jit.cache_hits`,
+`jit.cache_misses`; the listener is installed once, by this import) and
+routing builds (`routing.build_s`).  They record only when a function
+compiles or a routing misses its cache, never on a warm call, and are
+read through `metrics.snapshot()`: which runner compiled, and how long
+its trace, lowering and compile (or cache load) took.
 """
 from .trace import (Span, clear_trace, disable_tracing, enable_tracing,  # noqa
                     get_spans, save_chrome_trace, span_summary, trace,
                     tracing_enabled)
-from .metrics import (MetricsRegistry, cache_counters, metrics)  # noqa
+from .metrics import (MetricsRegistry, cache_counters,  # noqa
+                      install_compile_listeners, metrics)
 from .flight import link_rows, window_rows, LINK_COLUMNS, WINDOW_COLUMNS  # noqa
 from .report import (gini, link_load_summary, window_summary,  # noqa
                      write_link_reports, write_window_reports)
@@ -29,3 +39,5 @@ from .profile import (ProfileRegistry, clear_profiles, disable_profiling,  # noq
                       enable_profiling, get_profiles, profiling_enabled)
 from .bench import (BENCH_SCHEMA_VERSION, bench_doc, compare,  # noqa
                     load_bench, write_bench)
+
+install_compile_listeners()

@@ -20,6 +20,25 @@ sweep engine counts compiles this way: a *miss* delta counts new
 compiled programs exactly, where the old sum-of-entries subtraction
 could be shrunk by an LRU eviction between the two reads and
 misattribute compiles).
+
+Two more sources record into the registry always, whether tracing is on
+or off, and only when work is built — never on a warm call:
+
+  * **compile pipeline** (`install_compile_listeners`, installed once
+    per process when `repro.obs` is imported): JAX's own
+    `jax.monitoring` events, one observation per compile stage of each
+    jitted function, keyed by the function's name as JAX gives it —
+    `jit.trace_s:<fun>` (jaxpr trace: `runner`), `jit.lower_s:<fun>`
+    (MLIR lowering: `jit(runner)`) and `jit.compile_s:<fun>` (the
+    backend compile, or on a persistent-cache hit the retrieval and
+    load of the executable: `jit(runner)`).  A nested jit's trace lies
+    inside its caller's and is keyed by its own name, never added to
+    the caller's.  `jit.cache_load_s` totals the persistent-cache
+    retrievals (JAX names no function for them; they lie inside
+    `jit.compile_s`, so never add the two); `jit.cache_hits` /
+    `jit.cache_misses` count persistent-cache hits and writes;
+  * **routing builds** (`repro.core.routing.routing_for` on a cache
+    miss): `routing.build_s`.
 """
 from __future__ import annotations
 
@@ -185,3 +204,47 @@ def cache_counters() -> dict:
 
 #: process-wide registry (import `from repro.obs import metrics`)
 metrics = MetricsRegistry()
+
+
+# ---- compile-pipeline counters -------------------------------------------
+#: jax.monitoring duration events of one function's compile -> key prefix
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower_s",
+    "/jax/core/compile/backend_compile_duration": "jit.compile_s",
+}
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "jit.cache_hits",
+    "/jax/compilation_cache/cache_misses": "jit.cache_misses",
+}
+_listeners_installed = False
+
+
+def _on_compile_duration(event: str, secs: float, *, fun_name: str = "?",
+                         **_kw) -> None:
+    stage = _COMPILE_STAGES.get(event)
+    if stage is not None:
+        metrics.observe(f"{stage}:{fun_name}", secs)
+    elif event == _CACHE_LOAD:
+        metrics.observe("jit.cache_load_s", secs)
+
+
+def _on_compile_event(event: str, **_kw) -> None:
+    key = _CACHE_EVENTS.get(event)
+    if key is not None:
+        metrics.inc(key)
+
+
+def install_compile_listeners() -> None:
+    """Record JAX's compile-pipeline events into `metrics` (idempotent).
+    JAX emits them only while it traces, lowers or compiles, so a warm
+    call of a compiled function records nothing."""
+    global _listeners_installed
+    if _listeners_installed:
+        return
+    import jax.monitoring
+    jax.monitoring.register_event_duration_secs_listener(
+        _on_compile_duration)
+    jax.monitoring.register_event_listener(_on_compile_event)
+    _listeners_installed = True

@@ -722,3 +722,95 @@ def test_span_summary_aggregates():
     summ = span_summary(t.spans())
     assert summ["x"]["count"] == 3 and summ["y"]["count"] == 1
     assert summ["x"]["total_s"] >= summ["x"]["max_s"] >= 0
+
+
+# ---------------------------------------------------------------------
+# set-up counters: compile pipeline and routing builds (always on)
+# ---------------------------------------------------------------------
+
+def _jit_obs():
+    return {k: v for k, v in METRICS.snapshot().items()
+            if k.startswith("jit.")}
+
+
+def test_fresh_jit_records_each_compile_stage_under_its_name():
+    import jax
+
+    @jax.jit
+    def obs_probe_inner(x):
+        return x * 3
+
+    @jax.jit
+    def obs_probe_outer(x):
+        return obs_probe_inner(x) + 1
+
+    obs_probe_outer(np.arange(5, dtype=np.int32)).block_until_ready()
+    snap = METRICS.snapshot()
+    for key in ("jit.trace_s:obs_probe_outer",
+                "jit.lower_s:jit(obs_probe_outer)",
+                "jit.compile_s:jit(obs_probe_outer)",
+                "jit.trace_s:obs_probe_inner"):
+        assert snap[key]["count"] == 1 and snap[key]["sum"] >= 0, key
+    # the nested jit is traced inside its caller and never compiled alone
+    assert "jit.lower_s:jit(obs_probe_inner)" not in snap
+    assert "jit.compile_s:jit(obs_probe_inner)" not in snap
+
+
+def test_warm_calls_record_no_compile_observation(specs):
+    import jax
+    f = jax.jit(lambda x: x - 1)
+    x = np.ones(7, np.float32)
+    f(x).block_until_ready()
+    run_batch(specs[:1], RATES, CFG)
+    before = _jit_obs()
+    assert any(k.startswith("jit.compile_s:") for k in before)
+    f(x).block_until_ready()
+    run_batch(specs[:1], RATES, CFG)
+    assert _jit_obs() == before
+
+
+def test_compile_listeners_install_once():
+    import importlib
+
+    import jax
+    import repro.obs
+    importlib.reload(repro.obs)
+    repro.obs.install_compile_listeners()
+
+    @jax.jit
+    def obs_probe_once(x):
+        return x + 2
+
+    obs_probe_once(np.zeros(3, np.int32)).block_until_ready()
+    snap = METRICS.snapshot()
+    assert snap["jit.trace_s:obs_probe_once"]["count"] == 1
+    assert snap["jit.compile_s:jit(obs_probe_once)"]["count"] == 1
+
+
+def test_routing_build_time_observed_on_a_miss_only():
+    from repro.core import routing as RT
+    topo = T.build("hexamesh", 19)
+    RT.routing_cache_clear()
+    n0 = METRICS.snapshot().get("routing.build_s", {}).get("count", 0)
+    RT.routing_for(topo)
+    after_miss = METRICS.snapshot()["routing.build_s"]
+    assert after_miss["count"] == n0 + 1 and after_miss["sum"] > 0
+    RT.routing_for(topo)
+    assert METRICS.snapshot()["routing.build_s"] == after_miss
+
+
+def test_runner_carries_each_step_phase_scope():
+    """Each phase of the step is a named scope, so the compiled program's
+    instructions say which phase they belong to."""
+    from repro.core import simulator as sim
+    from repro.sweep.padding import stack_specs
+    r = build_routing(T.build("mesh", 16))
+    batch, shape = stack_specs([make_spec(r, TR.uniform(r.topo))])
+    runner = sim._make_batch_runner(shape.n, shape.p, shape.c, shape.d,
+                                    TCFG._replace(cycles=40, warmup=10),
+                                    "jnp")
+    hlo = runner.lower(batch, np.zeros((1, 2), np.float32)
+                       ).compile().as_text()
+    for scope in ("step_arrivals", "step_credits", "step_inject",
+                  "step_route", "step_alloc", "step_move", "step_flight"):
+        assert f'/{scope}/' in hlo, scope
