@@ -10,8 +10,8 @@ Scale/robustness knobs:
   * `chunk_size` streams a bucket in chunks of that many scenarios
     instead of one monolithic batch — bounds device memory for huge
     grids and gives `progress` callbacks something to report between
-    compiled runs (the engine's executable cache makes the chunks share
-    one compiled program per bucket shape);
+    compiled runs (the engine's executable cache makes chunks of one
+    padded shape share one compiled program);
   * `on_error="skip"` isolates partial failures: a chunk that raises
     marks only its own scenarios `status="failed"` (with the error
     message in the row), logs an `execute.chunk_failed` metrics event
@@ -79,9 +79,13 @@ def _progress_arity(cb) -> int:
         return 3
 
 
-def _run_chunk(engine: SweepEngine, bucket: Bucket, chunk: list,
-               single_program: bool = False) -> list:
-    """One engine call for `chunk`; returns raw result dicts in order."""
+def _run_chunk(engine: SweepEngine, bucket: Bucket, chunk: list) -> list:
+    """One engine call for `chunk`; returns raw result dicts in order.
+
+    The plan's bucket is already one engine call (`SweepEngine.group`),
+    so the engine runs it as such (`single_program`), never re-splitting
+    a merged bucket: a whole bucket runs at its key's shape, a chunk of
+    one at its own members' padded maximum."""
     if bucket.key.kind == "analytic":
         return [None] * len(chunk)
     rates = np.stack([ps.rates for ps in chunk]).astype(np.float32)
@@ -94,10 +98,8 @@ def _run_chunk(engine: SweepEngine, bucket: Bucket, chunk: list,
         else engine.cfg._replace(routing=bucket.key.routing)
     if bucket.key.kind == "workload":
         return engine.run_workloads(specs, [ps.sched_spec for ps in chunk],
-                                    rates, single_program=single_program,
-                                    cfg=cfg)
-    return engine.run_specs(specs, rates, single_program=single_program,
-                            cfg=cfg)
+                                    rates, single_program=True, cfg=cfg)
+    return engine.run_specs(specs, rates, single_program=True, cfg=cfg)
 
 
 def execute(pl: Plan, engine: SweepEngine | None = None,
@@ -132,8 +134,7 @@ def execute(pl: Plan, engine: SweepEngine | None = None,
                            kind=bucket.key.kind,
                            scenarios=len(chunk)) as sp:
                     try:
-                        out = _run_chunk(engine, bucket, chunk,
-                                         single_program=pl.single_program)
+                        out = _run_chunk(engine, bucket, chunk)
                     except Exception as e:   # noqa: BLE001 — isolate chunk
                         if on_error == "raise":
                             raise

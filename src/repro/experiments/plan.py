@@ -6,9 +6,12 @@
 rate grids — and groups the survivors into *buckets* that lower 1:1
 onto `SweepEngine` padded batches:
 
-  * bucket key = (kind, R, bucketed PadShape, bucketed phase count),
-    mirroring the engine's own shape-rounding policy so one bucket is
-    one engine group (one compiled program, typically reused);
+  * buckets come from the engine's own grouping policy
+    (`SweepEngine.group`): scenarios of one (kind, R, routing mode)
+    group by bucketed PadShape and phase count, and those groups merge
+    wherever one padded call costs no more device work than several —
+    one bucket is one engine call (one compiled program) at its key's
+    shape;
   * static scenarios and workload scenarios flow through the same
     pipeline — a workload scenario simply carries a compiled
     `SchedSpec` next to its `SimSpec` (its spec's traffic matrix is the
@@ -32,7 +35,7 @@ from repro.core.routing import cached_routing, routing_for
 from repro.faults import FaultError
 from repro.core.simulator import SimSpec, make_spec
 from repro.obs.trace import trace
-from repro.sweep.engine import SweepEngine, _round_up
+from repro.sweep.engine import SweepEngine
 from repro.sweep.padding import PadShape
 
 from .scenario import CustomTraffic, Experiment, Scenario
@@ -202,12 +205,12 @@ def plan(experiment: Experiment, engine: SweepEngine | None = None,
          single_program: bool = False) -> Plan:
     """Validate + resolve every scenario and bucket them for execution.
 
-    `engine` only contributes its shape-bucketing policy (so the plan's
-    buckets coincide with the engine groups executed later); planning
-    never compiles or runs anything.
+    `engine` only contributes its grouping policy (`SweepEngine.group`:
+    shape bucketing and cost-aware merging), so each bucket is one
+    engine call; planning never compiles or runs anything.
 
-    single_program=True coalesces all scenarios of one (kind, R, phase
-    bucket) into a single bucket that the executor runs as ONE compiled
+    single_program=True coalesces all scenarios of one (kind, R, routing
+    mode) into a single bucket that the executor runs as ONE compiled
     program padded to the group's max shape (the engine's
     `run_specs(..., single_program=True)` mode) — fewer compiles at the
     cost of padding small topologies to the largest shape present.
@@ -215,7 +218,8 @@ def plan(experiment: Experiment, engine: SweepEngine | None = None,
     engine = engine or SweepEngine(cfg=experiment.cfg)
     meas = experiment.cfg.cycles - experiment.cfg.warmup
     sim_backend = experiment.backend == "sim"
-    buckets: dict[BucketKey, Bucket] = {}
+    analytic_buckets: dict[BucketKey, Bucket] = {}
+    sims: list = []                       # [(PlannedScenario, tag)]
     skipped: list = []
     skip_codes: dict = {}
     with trace("experiment.plan", cat="experiments",
@@ -246,41 +250,32 @@ def plan(experiment: Experiment, engine: SweepEngine | None = None,
                     if schedule is not None else None
                 rates = np.asarray(
                     s.rates.resolve(analytic, routing=eff), np.float64)
-                shape = engine.bucket_shape(
-                    PadShape(n=spec.n, p=spec.p, c=spec.c, d=spec.d))
-                k = sched_spec.k if sched_spec is not None else 0
-                k_pad = _round_up(k, engine.k_round) \
-                    if engine.bucket and k else k
-                key = BucketKey(kind=s.kind, n_rates=len(rates),
-                                shape=shape, k_pad=k_pad, routing=eff)
-            else:
-                key = BucketKey(kind="analytic", n_rates=0, shape=None,
-                                k_pad=0, routing=eff)
             ps = PlannedScenario(index=i, scenario=s, topo=topo,
                                  routing=routing, traffic=tm,
                                  analytic=float(analytic), spec=spec,
                                  schedule=schedule, sched_spec=sched_spec,
                                  rates=rates)
-            buckets.setdefault(key,
-                               Bucket(key=key, items=[])).items.append(ps)
-    out = list(buckets.values())
-    if single_program and sim_backend:
-        merged: dict[tuple, Bucket] = {}
-        for b in out:
-            # routing is part of the merge key: the two modes compile
-            # different programs, so they can never share one executable
-            mk = (b.key.kind, b.key.n_rates, b.key.routing)
-            if mk not in merged:
-                merged[mk] = Bucket(key=b.key, items=list(b.items))
+            if sim_backend:
+                # routing is part of the tag: the two modes compile
+                # different programs, so they never share one executable
+                sims.append((ps, (s.kind, len(rates), eff)))
             else:
-                m = merged[mk]
-                specs = [ps.spec for ps in m.items + b.items]
-                m.key = BucketKey(
-                    kind=b.key.kind, n_rates=b.key.n_rates,
-                    shape=engine.bucket_shape(PadShape.of(specs)),
-                    k_pad=max(m.key.k_pad, b.key.k_pad),
-                    routing=b.key.routing)
-                m.items += b.items
-        out = list(merged.values())
+                key = BucketKey(kind="analytic", n_rates=0, shape=None,
+                                k_pad=0, routing=eff)
+                analytic_buckets.setdefault(
+                    key, Bucket(key=key, items=[])).items.append(ps)
+        out = list(analytic_buckets.values())
+        if sims:
+            groups = engine.group(
+                [PadShape(n=ps.spec.n, p=ps.spec.p, c=ps.spec.c,
+                          d=ps.spec.d) for ps, _ in sims],
+                [ps.sched_spec.k if ps.sched_spec is not None else 0
+                 for ps, _ in sims],
+                [tag for _, tag in sims], single_program=single_program)
+            out = [Bucket(key=BucketKey(kind=g.tag[0], n_rates=g.tag[1],
+                                        shape=g.shape, k_pad=g.k_pad,
+                                        routing=g.tag[2]),
+                          items=[sims[j][0] for j in g.idxs])
+                   for g in groups]
     return Plan(experiment=experiment, buckets=out, skipped=skipped,
                 single_program=single_program, skip_codes=skip_codes)

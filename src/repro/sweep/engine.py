@@ -5,11 +5,17 @@ per-topology recompile loop into a handful of batched compiled programs:
 
   1. specs are grouped by *bucketed* padded shape (dims rounded up to
      configurable multiples, batch size rounded up by replicating the
-     last spec, rate rows rounded up by repeating the last rate), so
-  2. adding one more topology or rate to a sweep usually re-runs the
-     SAME executable (`repro.core.simulator.get_batch_runner` caches per
-     padded shape; jit caches per batch shape), and
-  3. padding invariance (see `repro.sweep.padding`) guarantees results
+     last spec, rate rows rounded up by repeating the last rate),
+  2. shape groups then merge while one call at the merged shape costs no
+     more padded device work than the calls apart (`SweepEngine.group`,
+     estimated by `lane_cost`), so the inert tail lanes of one group
+     carry another group's specs instead,
+  3. adding one more topology or rate to a sweep often re-runs the SAME
+     executable (`repro.core.simulator.get_batch_runner` caches per
+     padded shape; jit caches per batch shape) — though a new spec can
+     change which groups merge, and so which executables run: merging
+     trades that reuse for never adding padded work, and
+  4. padding invariance (see `repro.sweep.padding`) guarantees results
      are bitwise-equal to the single-spec `simulate` path.
 
 Case-level evaluation moved to the declarative experiment API
@@ -23,8 +29,10 @@ experiment executor lowers onto.
 from __future__ import annotations
 
 import dataclasses
+import heapq
+import itertools
 import warnings
-from typing import NamedTuple, Sequence
+from typing import Hashable, NamedTuple, Sequence
 
 import numpy as np
 
@@ -63,6 +71,27 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m if m > 1 else x
 
 
+def lane_cost(shape: PadShape) -> int:
+    """Estimated per-cycle device work of one spec lane padded to `shape`.
+
+    The step is dominated by its [N, P+1, V] port-grid phases (route
+    lookup, allocation, move) and by per-channel arrivals and link
+    writes; V and the buffer depth are the same in every call of one
+    config, and the link ring is read one slot per cycle, so depth D
+    does not enter (DESIGN.md §6)."""
+    return shape.n * (shape.p + 1) + shape.c
+
+
+class SpecGroup(NamedTuple):
+    """One engine call: specs `idxs` padded to `shape` and `k_pad`.
+    `tag` is what all members share: the planner's (kind, R, routing),
+    None inside one engine call."""
+    tag: Hashable
+    shape: PadShape      # bucketed padded shape of the call
+    k_pad: int           # bucketed phase axis (0 = static)
+    idxs: tuple          # member positions, in input order
+
+
 @dataclasses.dataclass
 class SweepEngine:
     """Padded-batch sweep runner with a compiled-executable cache.
@@ -91,6 +120,99 @@ class SweepEngine:
                         p=shape.p,
                         c=_round_up(shape.c, self.c_mult),
                         d=_round_up(shape.d, self.d_mult))
+
+    def k_bucket(self, k: int) -> int:
+        return _round_up(k, self.k_round) if self.bucket and k else k
+
+    def call_cost(self, shape: PadShape, s_live: int) -> int:
+        """Padded device work per cycle and rate of one call of `s_live`
+        specs at `shape`: its padded spec lanes times `lane_cost`."""
+        s_pad = _round_up(s_live, self.s_round) if self.bucket else s_live
+        return s_pad * lane_cost(shape)
+
+    def group(self, shapes: Sequence[PadShape],
+              ks: Sequence[int] | None = None,
+              tags: Sequence[Hashable] | None = None,
+              single_program: bool = False) -> list[SpecGroup]:
+        """Partition specs into engine calls — the one grouping policy,
+        shared by `run_specs`/`run_workloads` and `experiments.plan`.
+
+        shapes: each spec's own `PadShape`; ks: its phase count (0 for a
+        static spec); tags: what members of one call must share (the
+        planner's kind, rate-grid length and routing mode).  Specs first
+        group by (tag, bucketed shape, bucketed phase count).  With
+        `single_program` each tag's groups become one call.  Otherwise,
+        when bucketing, a tag's groups merge greedily — largest saving
+        first, ties merged (one compile fewer) — while the merged call
+        costs no more padded work than the two apart:
+
+            call_cost(max(a, b), s_a + s_b)
+                <= call_cost(a, s_a) + call_cost(b, s_b)
+
+        with `max` elementwise and the phase axis padded to the larger.
+        The merges depend only on the multiset of (shape, phase count,
+        live count) groups, never on the specs' order.  Groups come back
+        in order of their first member.
+        """
+        n = len(shapes)
+        ks = ks if ks is not None else [0] * n
+        tags = tags if tags is not None else [None] * n
+        base: dict = {}
+        for i, (shape, k, tag) in enumerate(zip(shapes, ks, tags)):
+            key = (tag, self.bucket_shape(shape), self.k_bucket(k))
+            base.setdefault(key, []).append(i)
+        by_tag: dict = {}
+        for (tag, shape, k), idxs in base.items():
+            by_tag.setdefault(tag, []).append((shape, k, idxs))
+        out = []
+        for tag, groups in by_tag.items():
+            if single_program:
+                groups = [(PadShape.of([g[0] for g in groups]),
+                           max(g[1] for g in groups),
+                           [i for g in groups for i in g[2]])]
+            elif self.bucket and len(groups) > 1:
+                merged = self._merge(groups)
+                metrics.inc("sweep.merged_groups", len(groups) - len(merged))
+                groups = merged
+            out += [SpecGroup(tag, shape, k, tuple(sorted(idxs)))
+                    for shape, k, idxs in groups]
+        return sorted(out, key=lambda g: g.idxs[0])
+
+    def _merge(self, groups: list) -> list:
+        """Greedy cost-aware merging of (shape, k_pad, idxs) groups (see
+        `group`).  Candidate merges sit in a heap keyed by (-saving, the
+        pair's (shape, k_pad, live count)), so ties break on the groups'
+        contents, never on their order."""
+        def canon(g):
+            return g[0], g[1], len(g[2])
+
+        alive = dict(enumerate(sorted(groups, key=canon)))
+        heap: list = []
+
+        def push(a: int, b: int) -> None:
+            ga, gb = alive[a], alive[b]
+            saving = (self.call_cost(ga[0], len(ga[2]))
+                      + self.call_cost(gb[0], len(gb[2]))
+                      - self.call_cost(PadShape.of([ga[0], gb[0]]),
+                                       len(ga[2]) + len(gb[2])))
+            if saving >= 0:
+                heapq.heappush(heap, (-saving, sorted((canon(ga), canon(gb))),
+                                      a, b))
+
+        for a, b in itertools.combinations(list(alive), 2):
+            push(a, b)
+        nxt = len(alive)
+        while heap:
+            _, _, a, b = heapq.heappop(heap)
+            if a not in alive or b not in alive:
+                continue                       # stale: a side merged since
+            ga, gb = alive.pop(a), alive.pop(b)
+            alive[nxt] = (PadShape.of([ga[0], gb[0]]), max(ga[1], gb[1]),
+                          ga[2] + gb[2])
+            for c in list(alive)[:-1]:
+                push(c, nxt)
+            nxt += 1
+        return list(alive.values())
 
     # ---- core entry points ---------------------------------------------
     def run_specs(self, specs: Sequence[SimSpec], rates,
@@ -143,24 +265,10 @@ class SweepEngine:
             rates = np.broadcast_to(rates, (s, rates.shape[0])).copy()
         n_rates = rates.shape[1]
         r_pad = _round_up(n_rates, self.r_round) if self.bucket else n_rates
-
-        def k_bucket(i: int) -> int:
-            if schedules is None:
-                return 0
-            k = schedules[i].k
-            return _round_up(k, self.k_round) if self.bucket else k
-
-        groups: dict[tuple[PadShape, int], list[int]] = {}
-        if single_program:
-            key = (self.bucket_shape(PadShape.of(specs)),
-                   max(k_bucket(i) for i in range(s)))
-            groups[key] = list(range(s))
-        else:
-            for i, spec in enumerate(specs):
-                key = (self.bucket_shape(
-                    PadShape(n=spec.n, p=spec.p, c=spec.c, d=spec.d)),
-                    k_bucket(i))
-                groups.setdefault(key, []).append(i)
+        own = [PadShape(n=sp.n, p=sp.p, c=sp.c, d=sp.d) for sp in specs]
+        ks = [sc.k for sc in schedules] if schedules is not None \
+            else [0] * s
+        groups = self.group(own, ks, single_program=single_program)
 
         # compile accounting via the metrics registry's monotonic cache
         # counters (DESIGN.md §13): a runner-cache *miss* delta counts
@@ -169,7 +277,8 @@ class SweepEngine:
         # between the two reads and misattributed compiles.
         before = cache_counters()["cache.runner.misses"]
         results: list = [None] * s
-        for (shape, k_pad), idxs in groups.items():
+        for _, shape, k_pad, idxs in groups:
+            idxs = list(idxs)
             g_specs = [specs[i] for i in idxs]
             g_scheds = [schedules[i] for i in idxs] \
                 if schedules is not None else None
@@ -182,6 +291,8 @@ class SweepEngine:
             s_live = len(g_specs)
             s_pad = _round_up(s_live, self.s_round) \
                 if self.bucket else s_live
+            shapes = len({(self.bucket_shape(own[i]), self.k_bucket(ks[i]))
+                          for i in idxs})
             while len(g_specs) < s_pad:           # replicate an inert tail
                 g_specs.append(g_specs[-1])
                 g_rates = np.concatenate([g_rates, g_rates[-1:]], axis=0)
@@ -189,11 +300,18 @@ class SweepEngine:
                     g_scheds.append(g_scheds[-1])
             # bucket-fill attrs (DESIGN.md §16): live vs padded batch
             # rows/rates — with the per-spec pad_fill fractions on the
-            # results, the complete pad-waste picture for this dispatch
+            # results, the complete pad-waste picture for this dispatch.
+            # `shapes` counts the shape groups this call carries;
+            # work_live / work_pad (Σ `lane_cost` of the live specs at
+            # their own shapes / of every padded lane at the call's) add
+            # the port and channel padding that s_live / s_pad miss
             with trace("sweep.group", cat="sweep", specs=len(g_specs),
                        shape=str(shape), k_pad=k_pad,
                        s_live=s_live, s_pad=s_pad,
                        r_live=n_rates, r_pad=g_rates.shape[1],
+                       shapes=shapes,
+                       work_live=sum(lane_cost(own[i]) for i in idxs),
+                       work_pad=s_pad * lane_cost(shape),
                        kind="static" if g_scheds is None else "workload"):
                 out = sim.run_batch(g_specs, g_rates, cfg,
                                     pad_shape=shape, schedules=g_scheds,

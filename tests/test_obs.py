@@ -380,7 +380,8 @@ def test_engine_compile_stats_survive_evictions():
     eng = SweepEngine(cfg=tiny, bucket=False)
     try:
         sim.set_runner_cache_limit(1)   # every group evicts the other
-        eng.run_specs(specs, rates)     # 2 shapes -> 2 compiles
+        # bucket=False never merges: 2 shapes -> 2 compiles
+        eng.run_specs(specs, rates)
         assert eng.stats["compiles"] == 2
         eng.run_specs(specs, rates)     # both cold again (evicted)
         assert eng.stats["compiles"] == 4
@@ -814,3 +815,32 @@ def test_runner_carries_each_step_phase_scope():
     for scope in ("step_arrivals", "step_credits", "step_inject",
                   "step_route", "step_alloc", "step_move", "step_flight"):
         assert f'/{scope}/' in hlo, scope
+
+
+def test_merged_call_reports_shapes_work_and_merges(specs):
+    """A merged engine call names the shape groups it carries and its
+    live vs padded lane work; `sweep.merged_groups` counts each group a
+    merge absorbs, once, in the planner or in the engine."""
+    from repro.sweep.engine import lane_cost
+    enable_tracing()
+    clear_trace()
+    eng = SweepEngine(cfg=CFG)
+    m0 = METRICS.get("sweep.merged_groups")
+    eng.run_specs(specs, RATES)
+    assert METRICS.get("sweep.merged_groups") - m0 == 1
+    (sp,) = [s for s in get_spans() if s.name == "sweep.group"]
+    call = eng.bucket_shape(PadShape.of(specs))
+    assert sp.args["shape"] == str(call)
+    assert sp.args["shapes"] == 2
+    # mesh16: 16*5 + 48; folded_hexa_torus36: 36*7 + 208
+    assert sp.args["work_live"] == 128 + 460 == sum(
+        lane_cost(PadShape.of([s])) for s in specs)
+    assert sp.args["work_pad"] == 4 * (40 * 7 + 224) == 4 * lane_cost(call)
+    exp = X.Experiment([X.Scenario(name, n, rates=X.ExplicitRates((0.1,)))
+                        for name, n in HETERO], cfg=CFG)
+    m1 = METRICS.get("sweep.merged_groups")
+    pl = X.plan(exp, eng)
+    assert len(pl.buckets) == 1
+    assert METRICS.get("sweep.merged_groups") - m1 == 1
+    X.execute(pl, engine=eng)             # runs the bucket as one call
+    assert METRICS.get("sweep.merged_groups") - m1 == 1

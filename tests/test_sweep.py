@@ -275,3 +275,101 @@ def test_engine_cfg_override_routes_adaptively(hetero_specs):
     single = run_batch([specs[0]], rates[None, :], CFG)[0]
     np.testing.assert_array_equal(static[0]["delivered"],
                                   single["delivered"])
+
+
+# ---------------------------------------------------------------------
+# cost-aware merging of shape groups (DESIGN.md §6)
+# ---------------------------------------------------------------------
+
+# four N=16 topologies whose bucketed (p, c) all differ
+MERGE4 = ("mesh", "folded_hexa_torus", "honeycomb_mesh", "octamesh")
+
+# the bucketed shapes of the N=64 Table III grid (19 topologies x
+# {organic, glass}), each with its live spec count
+TABLE3 = [(PadShape(64, 3, 192, 12), 4), (PadShape(64, 4, 224, 12), 8),
+          (PadShape(64, 4, 256, 12), 10), (PadShape(64, 5, 256, 12), 2),
+          (PadShape(64, 6, 288, 12), 2), (PadShape(64, 6, 352, 12), 2),
+          (PadShape(64, 6, 384, 12), 4), (PadShape(64, 8, 448, 12), 2),
+          (PadShape(64, 8, 512, 12), 2), (PadShape(64, 14, 896, 12), 2)]
+
+
+@pytest.fixture(scope="module")
+def merge4_specs():
+    specs = []
+    for name in MERGE4:
+        r = build_routing(T.build(name, 16))
+        specs.append(make_spec(r, TR.uniform(r.topo)))
+    return specs
+
+
+def _calls(groups) -> list:
+    return sorted((g.shape, g.k_pad, len(g.idxs)) for g in groups)
+
+
+def test_single_spec_groups_merge_into_one_call_bitwise(merge4_specs):
+    """Four single-spec groups of different (p, c) fill one call's four
+    lanes; every counter equals the unbucketed run's, one call per
+    shape."""
+    eng = SweepEngine(cfg=CFG)
+    own = [PadShape.of([s]) for s in merge4_specs]
+    assert len({eng.bucket_shape(sh) for sh in own}) == 4
+    (g,) = eng.group(own)
+    assert g.idxs == (0, 1, 2, 3)
+    assert g.shape == eng.bucket_shape(PadShape.of(merge4_specs))
+    rates = np.array([0.05, 0.2, 0.5], np.float32)
+    merged = eng.run_specs(merge4_specs, rates)
+    assert eng.stats["groups"] == 1
+    apart_eng = SweepEngine(cfg=CFG, bucket=False)
+    apart = apart_eng.run_specs(merge4_specs, rates)
+    assert apart_eng.stats["groups"] == 4
+    for a, b in zip(apart, merged):
+        for k in RAW:
+            np.testing.assert_array_equal(a[k], b[k], err_msg=k)
+
+
+def test_full_small_group_stays_apart_from_wide_one():
+    """Four small specs fill their own call; one call at the wide shape
+    would pad them into four more wide lanes.  Two small specs fit the
+    wide call's inert lanes instead."""
+    eng = SweepEngine()
+    small, wide = PadShape(16, 3, 36, 9), PadShape(16, 14, 224, 9)
+    apart = eng.group([small] * 4 + [wide] * 2)
+    assert _calls(apart) == [(eng.bucket_shape(small), 0, 4),
+                             (eng.bucket_shape(wide), 0, 2)]
+    (g,) = eng.group([small] * 2 + [wide] * 2)
+    assert g.shape == eng.bucket_shape(wide) and len(g.idxs) == 4
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 2 ** 31 + 5])
+def test_merges_ignore_spec_order(seed):
+    """The Table III grid's calls depend only on its shapes: 10 shape
+    groups become 7 calls and 30848 units of padded work (from 40960),
+    in any order of the specs."""
+    eng = SweepEngine()
+    shapes = [sh for sh, live in TABLE3 for _ in range(live)]
+    order = np.random.default_rng(seed).permutation(len(shapes))
+    groups = eng.group([shapes[i] for i in order])
+    want = eng.group(shapes)
+    assert _calls(groups) == _calls(want)
+    members = sorted(sorted(shapes[order[i]] for i in g.idxs)
+                     for g in groups)
+    assert members == sorted(sorted(shapes[i] for i in g.idxs)
+                             for g in want)
+    assert len(groups) == 7
+    assert sum(eng.call_cost(g.shape, len(g.idxs)) for g in groups) \
+        == 30848
+    assert sum(eng.call_cost(sh, live) for sh, live in TABLE3) == 40960
+
+
+def test_merge_keeps_tags_apart_pads_phases_and_needs_bucketing():
+    """Groups of different tags (kind, R, routing) never share a call;
+    merged workload groups pad the phase axis to the larger; a
+    bucket=False engine never merges."""
+    a, b = PadShape(16, 4, 48, 9), PadShape(16, 6, 96, 9)
+    eng = SweepEngine()
+    assert len(eng.group([a, b], tags=["static", "adaptive"])) == 2
+    (g,) = eng.group([a, b], ks=[2, 5])
+    assert g.k_pad == 6 and g.idxs == (0, 1)
+    assert len(SweepEngine(bucket=False).group([a, b])) == 2
+    (one,) = SweepEngine(bucket=False).group([a, b], single_program=True)
+    assert one.shape == PadShape.of([a, b])
