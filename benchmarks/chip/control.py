@@ -38,12 +38,12 @@ def main(argv=None) -> int:
         refs = {i: H.reference_counters(cell, planned[i]) for i in sample}
         control = {i: H.reference_counters(cell, planned[i], rotate=False)
                    for i in sample}
-        checks = H.compare([control], refs)
+        checks, compared = H.compare([control], refs)
         print(json.dumps({"workload": args.workload, "seed": seed,
                           "scenarios": [planned[i].topology + "/" +
                                         planned[i].substrate + "/" +
                                         planned[i].pattern for i in sample],
-                          "control": checks,
+                          "control": checks, "compared": compared,
                           "seconds": time.perf_counter() - t0}), flush=True)
     return 0
 

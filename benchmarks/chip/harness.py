@@ -16,7 +16,10 @@ A run:
            with trace=True);
   check    the counters of every window pass, for a sample of the
            scenarios drawn from the seed, against the plain reference
-           (`reference.py`), after the peak device memory is read.
+           (`reference.py`), after the peak device memory is read: the
+           four raw counters, and with `telemetry` on the flight
+           recorder's integer counters too, channels matched by their
+           (source, destination) chiplets.
 """
 from __future__ import annotations
 
@@ -40,6 +43,17 @@ from .peaks import peaks_for
 ROOT = Path(__file__).resolve().parents[2]
 HERE = Path(__file__).resolve().parent
 RAW = ("delivered", "offered_n", "accepted_n", "lat_sum")
+#: the flight recorder's integer counters (`telemetry`), and those of
+#: its time windows (`telemetry_windows` > 0)
+FLIGHT = ("link_busy", "link_stall", "link_occ_sum", "inj_node",
+          "eject_node", "lat_hist")
+FLIGHT_W = ("link_busy_w", "link_stall_w", "link_occ_w", "inj_node_w",
+            "eject_node_w", "window_cycles")
+#: the channel axis of each per-channel counter
+CHANNEL_AXIS = {"link_busy": 1, "link_stall": 1, "link_occ_sum": 1,
+                "link_busy_w": 2, "link_stall_w": 2, "link_occ_w": 2}
+#: where a result carries each channel's (source, destination) chiplets
+CHANNELS = "channels"
 
 
 @dataclasses.dataclass
@@ -145,11 +159,22 @@ def plan_cell(cell: Cell, seed: int) -> list:
 
 
 def sim_config(cfg: dict):
+    """The `SimConfig` of a configuration: every key that names one of
+    its fields, as written, and `sim_seed` as `seed`."""
     from repro.core.simulator import SimConfig
-    return SimConfig(n_vcs=cfg["n_vcs"], buf_depth=cfg["buf_depth"],
-                     cycles=cfg["cycles"], warmup=cfg["warmup"],
-                     seed=cfg["sim_seed"], alloc=cfg["alloc"],
-                     telemetry=cfg["telemetry"], routing=cfg["routing"])
+    kw = {k: v for k, v in cfg.items() if k in SimConfig._fields}
+    kw["seed"] = cfg["sim_seed"]
+    return SimConfig(**kw)
+
+
+def counter_keys(cfg: dict) -> tuple:
+    """The integer counters a configuration switches on."""
+    keys = RAW
+    if cfg.get("telemetry"):
+        keys += FLIGHT
+        if cfg.get("telemetry_windows"):
+            keys += FLIGHT_W
+    return keys
 
 
 def experiment(cell: Cell, planned: list):
@@ -165,13 +190,25 @@ def experiment(cell: Cell, planned: list):
 
 
 def run_pass(exp) -> list:
-    """One pass; returns per scenario its raw counters (None if the
-    scenario failed)."""
+    """One pass; returns per scenario its integer counters (None if the
+    scenario failed).  With the flight recorder on, each also carries
+    under `CHANNELS` its channels' [c, 2] (source, destination), read
+    where the program's own link rows (`ResultFrame.link_rows`) read
+    them: building the rows would cost the window ~0.1 s a pass."""
     import repro.experiments as X
     frame = X.run(exp, on_error="skip")
-    return [({k: np.asarray(res[k]) for k in RAW}
-             if row["status"] == "ok" and res is not None else None)
-            for row, res in zip(frame.rows, frame.results)]
+    keys = counter_keys(exp.cfg._asdict())
+    out = []
+    for i, (row, res) in enumerate(zip(frame.rows, frame.results)):
+        if row["status"] != "ok" or res is None:
+            out.append(None)
+            continue
+        got = {k: np.asarray(res[k]) for k in keys}
+        if exp.cfg.telemetry:
+            routing = frame.planned[i].routing
+            got[CHANNELS] = np.stack([routing.ch_src, routing.ch_dst], 1)
+        out.append(got)
+    return out
 
 
 def padded_scenarios(exp) -> set:
@@ -210,28 +247,84 @@ def check_sample(cell: Cell, planned: list, padded: set, seed: int) -> list:
 
 
 def reference_counters(cell: Cell, p: Planned, rotate: bool = True) -> dict:
+    """The reference's counters of one scenario; with the flight
+    recorder on, also its channels' (source, destination) under
+    `CHANNELS`."""
     cfg = cell.config
-    return R.simulate(p.net, p.traffic, p.rates, cycles=cfg["cycles"],
-                      warmup=cfg["warmup"], n_vcs=cfg["n_vcs"],
-                      buf_depth=cfg["buf_depth"], seed=cfg["sim_seed"],
-                      rotate=rotate)
+    ref = R.simulate(p.net, p.traffic, p.rates, cycles=cfg["cycles"],
+                     warmup=cfg["warmup"], n_vcs=cfg["n_vcs"],
+                     buf_depth=cfg["buf_depth"], seed=cfg["sim_seed"],
+                     rotate=rotate, telemetry=cfg.get("telemetry", False),
+                     windows=cfg.get("telemetry_windows", 0))
+    if cfg.get("telemetry"):
+        ref[CHANNELS] = np.stack([p.net.ch_src, p.net.ch_dst], 1)
+    return ref
 
 
-def compare(passes: list, refs: dict) -> dict:
-    """Numbers compared, each {value, limit}.  `passes` holds each window
+def _channel_index(ends: np.ndarray) -> dict:
+    """{(source, destination): channel}; two channels of one pair are an
+    error, never merged."""
+    index = {}
+    for c, pair in enumerate(map(tuple, ends.tolist())):
+        if pair in index:
+            raise ValueError(f"two channels from chiplet {pair[0]} to "
+                             f"{pair[1]}: channels {index[pair]} and {c}")
+        index[pair] = c
+    return index
+
+
+def channel_order(got_ends: np.ndarray, ref_ends: np.ndarray):
+    """Indices into the program's channel axis in the reference's channel
+    order, matched by (source, destination); None where the two sets of
+    channels differ."""
+    got, ref = _channel_index(got_ends), _channel_index(ref_ends)
+    if got.keys() != ref.keys():
+        return None
+    return np.array([got[pair] for pair in ref], np.int64)
+
+
+def _differing(got: dict, ref: dict, key: str, order) -> int:
+    """Elements of `ref[key]` that `got` does not reproduce."""
+    want = ref[key]
+    if key not in got:
+        return int(want.size)
+    have = np.asarray(got[key], np.int64)
+    if key in CHANNEL_AXIS:
+        axis = CHANNEL_AXIS[key]
+        if order is None or have.ndim <= axis or \
+                have.shape[axis] != len(order):
+            return int(want.size)
+        have = np.take(have, order, axis=axis)
+    if have.shape != want.shape:
+        return int(want.size)
+    return int(np.sum(have != want))
+
+
+def compare(passes: list, refs: dict) -> tuple:
+    """Numbers compared, each {value, limit}, and per counter key
+    [elements compared, elements that differ].  `passes` holds each window
     pass's counters per scenario, `refs` the reference's counters of the
-    sampled scenarios."""
-    mismatches = 0
+    sampled scenarios; every key the reference returned is compared,
+    per-channel counters channel by channel (`channel_order`)."""
+    mismatches, per_key = 0, {}
     for counters in passes:
         for i, ref in refs.items():
             got = counters[i]
-            for k in RAW:
-                mismatches += (int(ref[k].size) if got is None else
-                               int(np.sum(np.asarray(got[k], np.int64)
-                                          != ref[k])))
+            order = None
+            if got is not None and CHANNELS in ref and CHANNELS in got:
+                order = channel_order(got[CHANNELS], ref[CHANNELS])
+            for k in ref:
+                if k == CHANNELS:
+                    continue
+                bad = (int(ref[k].size) if got is None else
+                       _differing(got, ref, k, order))
+                tally = per_key.setdefault(k, [0, 0])
+                tally[0] += int(ref[k].size)
+                tally[1] += bad
+                mismatches += bad
     failed = sum(c is None for counters in passes for c in counters)
-    return {"counter_mismatches": {"value": mismatches, "limit": 0},
-            "failed_scenarios": {"value": failed, "limit": 0}}
+    return ({"counter_mismatches": {"value": mismatches, "limit": 0},
+             "failed_scenarios": {"value": failed, "limit": 0}}, per_key)
 
 
 # ---------------------------------------------------------------------
@@ -312,7 +405,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 
     sample = check_sample(cell, planned, padded, seed)
     refs = {i: reference_counters(cell, planned[i]) for i in sample}
-    checks = compare(passes, refs)
+    checks, compared = compare(passes, refs)
     checks["window_compiles"] = {"value": compiles, "limit": 0}
     failed = sum(len(planned[i].rates) for c in passes
                  for i, x in enumerate(c) if x is None)
@@ -339,9 +432,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                                            "unit": m["unit"]}
                                for m in cell.end_to_end},
                       device=device)
+    result["compared"] = compared
     result["checks"] = checks
     log(f"checked {len(sample)} scenario(s) x {len(planned[0].rates)} rates "
-        f"against the reference over {len(passes)} pass(es)")
+        f"against the reference over {len(passes)} pass(es); elements "
+        f"compared / differing per counter: {compared}")
     for name, c in checks.items():
         print(f"check {name} {c['value']} limit {c['limit']}", file=sys.stderr)
     return result

@@ -16,7 +16,9 @@ substrate and the chiplet area) it rebuilds, on its own:
     link pipelines, credit-based flow control with 4-flit buffers on
     each VC, injection drawn from a counter hash of (seed, cycle, node),
     table routing, and two-phase separable switch allocation with a
-    rotating priority over VCs and then over input ports.
+    rotating priority over VCs and then over input ports;
+  * on request, the flight recorder's integer counters (per channel,
+    per node, a latency histogram), whole or in time windows.
 
 It runs one (spec, rates) lane set at its own unpadded shape, so it
 knows nothing of buckets, padding, vmaps, the scan or the kernel.
@@ -34,6 +36,11 @@ ROUTER_NS, PHY_NS = 3.0, 2.0
 C_MM_PER_NS = 299.792458
 EJECT = -2
 INF = 2 ** 30
+LAT_HIST_BINS = 16
+#: each flight-recorder aggregate and its key in time windows
+WINDOWED = {"link_busy": "link_busy_w", "link_stall": "link_stall_w",
+            "link_occ_sum": "link_occ_w", "inj_node": "inj_node_w",
+            "eject_node": "eject_node_w"}
 
 _GOLD = np.uint32(0x9E3779B9)
 _MIX_T = np.uint32(0x85EBCA6B)
@@ -217,12 +224,33 @@ def unit(bits: np.ndarray) -> np.ndarray:
 
 def simulate(net: Network, traffic: np.ndarray, rates, *, cycles: int,
              warmup: int, n_vcs: int, buf_depth: int, seed: int,
-             rotate: bool = True) -> dict:
+             rotate: bool = True, telemetry: bool = False,
+             windows: int = 0) -> dict:
     """Raw counters of one spec at each offered rate (lanes L = rates).
 
     Returns int64 arrays [L]: `delivered`, `offered_n`, `accepted_n`
     and `lat_sum`, counted over cycles warmup..cycles-1.  rotate=False
     freezes the allocator's rotating priority (the control).
+
+    telemetry=True adds the flight recorder's counters over the same
+    cycles, channels in this network's order:
+
+      link_busy [L, C]       flits that left on each channel;
+      link_stall [L, C]      head flits that are valid, not ejecting and
+                             without a credit for the channel their route
+                             names, charged to that channel;
+      link_occ_sum [L, C, V] flits held in each channel's downstream
+                             input buffer, per VC, read after arrivals and
+                             injection and before the winners leave;
+      inj_node, eject_node [L, n]  flits injected / ejected per node;
+      lat_hist [L, 16]       ejected flits by latency: bin h holds
+                             latencies in [2^(h-1), 2^h), the last bin
+                             everything from 2^14 up.
+
+    windows=W > 0 (with telemetry) adds `link_busy_w`, `link_stall_w`,
+    `link_occ_w`, `inj_node_w`, `eject_node_w`, each with a window axis
+    after the lane axis (measured cycle t falls in window
+    ((t - warmup) * W) // (cycles - warmup)), and `window_cycles` [W].
     """
     n, P, C, D = net.n, net.p, net.c, net.d
     V, B, PI = n_vcs, buf_depth, net.p + 1
@@ -245,6 +273,23 @@ def simulate(net: Network, traffic: np.ndarray, rates, *, cycles: int,
     accepted = np.zeros(L, np.int64)
     lat_sum = np.zeros(L, np.int64)
     rr = 0
+    meas = cycles - warmup
+    if windows and not telemetry:
+        raise ValueError("windows bin the flight recorder: telemetry=True")
+    if not 0 <= windows <= meas:
+        raise ValueError(f"windows={windows} outside 0..{meas}")
+    flight = {}
+    if telemetry:
+        flight = {"link_busy": np.zeros((L, C), np.int64),
+                  "link_stall": np.zeros((L, C), np.int64),
+                  "link_occ_sum": np.zeros((L, C, V), np.int64),
+                  "inj_node": np.zeros((L, n), np.int64),
+                  "eject_node": np.zeros((L, n), np.int64)}
+        lat_hist = np.zeros((L, LAT_HIST_BINS), np.int64)
+        hist_edges = 2 ** np.arange(LAT_HIST_BINS - 1)
+    binned = {WINDOWED[k]: np.zeros((L, windows) + v.shape[1:], np.int64)
+              for k, v in flight.items()} if windows else {}
+    window_cycles = np.zeros(windows, np.int64)
 
     li = np.arange(L)
     nodes = np.arange(n)
@@ -309,6 +354,17 @@ def simulate(net: Network, traffic: np.ndarray, rates, *, cycles: int,
                               axis=2)
         have = cred.reshape(-1)[cred_base + np.clip(op_slot, 0, P) * V] > 0
         eligible = valid & (op_slot >= 0) & (have | is_eject)
+        if telemetry and m:
+            cyc = {k: np.zeros((L,) + v.shape[1:], np.int64)
+                   for k, v in flight.items()}
+            cyc["link_occ_sum"][:] = cnt[:, net.ch_dst, net.ch_in_port, :]
+            cyc["inj_node"][:] = do
+            sl, sn, sp, sv = np.nonzero(valid & (op_slot >= 0) & ~is_eject
+                                        & ~have)
+            s_ch = out_ch[sn, op_slot[sl, sn, sp, sv]]
+            if (s_ch < 0).any():
+                raise RuntimeError("a route names a port with no channel")
+            np.add.at(cyc["link_stall"], (sl, s_ch), 1)
 
         # switch allocation: a) one VC per input port, b) one input port
         # per output slot, each by rotating priority (lowest index on ties)
@@ -357,5 +413,42 @@ def simulate(net: Network, traffic: np.ndarray, rates, *, cycles: int,
         credits[tl, tn, to, tv] -= 1
         rr = (rr + 1) % (V * PI)
 
-    return dict(delivered=delivered, offered_n=offered, accepted_n=accepted,
-                lat_sum=lat_sum)
+        if telemetry and m:
+            np.add.at(cyc["link_busy"], (tl, oc), 1)
+            np.add.at(cyc["eject_node"], (wl[ej], wn[ej]), 1)
+            lat = t - w_t[ej]
+            np.add.at(lat_hist, (wl[ej], np.searchsorted(hist_edges, lat,
+                                                         side="right")), 1)
+            for k, v in cyc.items():
+                flight[k] += v
+            if windows:
+                w = (t - warmup) * windows // meas
+                window_cycles[w] += 1
+                for k, v in cyc.items():
+                    binned[WINDOWED[k]][:, w] += v
+
+    out = dict(delivered=delivered, offered_n=offered, accepted_n=accepted,
+               lat_sum=lat_sum)
+    if telemetry:
+        out.update(flight, lat_hist=lat_hist)
+        _check_flight(out, binned, window_cycles, meas)
+        if windows:
+            out.update(binned, window_cycles=window_cycles)
+    return out
+
+
+def _check_flight(out: dict, binned: dict, window_cycles, meas: int):
+    """The recorder's own conservation laws; a breach is a fault of this
+    reference, so it raises."""
+    laws = [("inj_node", out["inj_node"].sum(1), out["accepted_n"]),
+            ("eject_node", out["eject_node"].sum(1), out["delivered"]),
+            ("lat_hist", out["lat_hist"].sum(1), out["delivered"])]
+    for k, wk in WINDOWED.items():
+        if wk in binned:
+            laws.append((wk, binned[wk].sum(1), out[k]))
+    if len(window_cycles):
+        laws.append(("window_cycles", window_cycles.sum(), meas))
+    for name, got, want in laws:
+        if not np.array_equal(got, want):
+            raise RuntimeError(f"reference flight recorder: {name} does "
+                               f"not add up")

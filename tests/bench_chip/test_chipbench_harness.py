@@ -133,9 +133,10 @@ def test_pinned_layouts_are_the_programs(fname):
     np.testing.assert_array_equal(edges, want_edges)
 
 
-def test_table3_lane_fill_is_38_of_52():
-    """The flagship plan: 38 live spec lanes of 52 run, all at N=64 with
-    8 of 8 rates, as the engine's `sweep.group` spans would report."""
+def test_table3_lane_fill_is_38_of_40():
+    """The flagship plan: 38 live spec lanes of 40 run in 7 calls, all at
+    N=64 with 8 of 8 rates, as the engine's `sweep.group` spans would
+    report."""
     import repro.experiments as X
     from repro.sweep.engine import _round_up
     cell = H.load_cell("paper_n64.table3_uniform")
@@ -149,9 +150,38 @@ def test_table3_lane_fill_is_38_of_52():
     ctx = H.MetricContext(red=H.TRD.Reduced((0, 1), [], [], [], spans),
                           config=cell.config, peak={},
                           window_wall_ns=1.0)
-    assert len(buckets) == 10
-    assert H.load_metric("lane_fill")(ctx) == pytest.approx(100 * 38 / 52)
+    assert len(buckets) == 7
+    assert sum(len(b.items) for b in buckets) == 38
+    assert sum(s[3]["s_pad"] for s in spans) == 40
+    assert H.load_metric("lane_fill")(ctx) == pytest.approx(95.0)
     assert H.padded_scenarios(exp)
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
+def test_sim_config_is_unchanged(config):
+    """Every committed configuration gets the `SimConfig` it always had
+    (so the same runners and compile-cache keys)."""
+    from repro.core.simulator import SimConfig
+    cfg = json.loads((ROOT / f"benchmarks/chip/configs/{config}.json")
+                     .read_text())
+    assert H.sim_config(cfg) == SimConfig(
+        n_vcs=cfg["n_vcs"], buf_depth=cfg["buf_depth"],
+        cycles=cfg["cycles"], warmup=cfg["warmup"], seed=cfg["sim_seed"],
+        alloc=cfg["alloc"], telemetry=cfg["telemetry"],
+        routing=cfg["routing"])
+    assert H.sim_config(cfg).telemetry_windows == 0
+    assert H.counter_keys(cfg) == H.RAW
+
+
+def test_sim_config_takes_every_field():
+    cfg = dict(tiny_cell().config, telemetry=True, telemetry_windows=4,
+               routing="adaptive", seed=99)
+    sc = H.sim_config(cfg)
+    assert (sc.telemetry, sc.telemetry_windows, sc.routing, sc.seed) == \
+        (True, 4, "adaptive", cfg["sim_seed"])
+    assert H.counter_keys(cfg) == H.RAW + H.FLIGHT + H.FLIGHT_W
+    assert H.counter_keys(dict(cfg, telemetry_windows=0)) == \
+        H.RAW + H.FLIGHT
 
 
 def test_peaks_reject_unknown_devices():
@@ -194,3 +224,32 @@ def test_tiny_traced_run_end_to_end_on_cpu(tiny_layouts, monkeypatch):
     assert res["device"]["window_s"] > 0
     assert res["breakdown"]["idle_gaps"]
     assert list(res)[-1] == "checks"
+
+
+def test_tiny_flight_recorder_run_on_cpu(tiny_layouts):
+    """A configuration with the flight recorder on and 4 windows runs
+    through `run_cell` as it stands; every flight-recorder counter is
+    compared with the reference, element by element."""
+    cell = tiny_cell(patterns=["uniform"])
+    cell.config.update(telemetry=True, telemetry_windows=4)
+    res = H.run_cell(cell, 2 ** 31 + 97, 0.2, False, time.perf_counter(),
+                     jax.devices(), log=lambda *_: None)
+    assert res["correct"], (res["checks"], res["compared"])
+    assert set(res["compared"]) == set(H.RAW + H.FLIGHT + H.FLIGHT_W)
+    for k, (elements, differing) in res["compared"].items():
+        assert elements > 0 and differing == 0, k
+    assert list(res)[-1] == "checks"
+
+
+def test_channels_are_the_programs_link_rows(tiny_layouts):
+    """The channel ends a pass carries are those of the program's own
+    per-link rows, channel by channel."""
+    import repro.experiments as X
+    cell = tiny_cell(patterns=["uniform"])
+    cell.config.update(telemetry=True)
+    exp = H.experiment(cell, H.plan_cell(cell, 5))
+    frame = X.run(exp, on_error="skip")
+    for i, got in enumerate(H.run_pass(exp)):
+        rows = frame.link_rows(i, rate_index=0)
+        np.testing.assert_array_equal(
+            got[H.CHANNELS], [(r["src"], r["dst"]) for r in rows])
