@@ -961,10 +961,12 @@ def run_batch(specs, rates, cfg: SimConfig = SimConfig(), *,
                                   resolve_alloc(cfg.alloc), kmax)
         args = (batch, jnp.asarray(rates), sbatch)
     fills = _pad_fill(specs, shape, schedules, kmax)
-    if profiling_enabled():
-        from repro.obs.profile import record_runner_profile
-        record_runner_profile(shape, cfg, resolve_alloc(cfg.alloc), kmax,
-                              runner, args)
+    if profiling_enabled() or _tracing():
+        # note the runner for its phase map, and profile it if asked
+        # (repro.obs.profile); off, this is two attribute reads
+        from repro.obs.profile import record_runner
+        record_runner(shape, cfg, resolve_alloc(cfg.alloc), kmax,
+                      runner, args)
     # dispatch vs wait split (DESIGN.md §13): the dispatch span covers
     # trace+compile on a cold executable (jit compiles synchronously at
     # dispatch) plus argument transfer; the wait span is the device
@@ -990,11 +992,18 @@ def run_batch(specs, rates, cfg: SimConfig = SimConfig(), *,
     telw = None
     win_cycles = None
     if cfg.telemetry:
+        from repro.obs.metrics import metrics
         off = 8 if schedules is not None else 4
-        tel = tuple(np.asarray(raw[off + j]) for j in range(6))
-        if cfg.telemetry_windows:
-            telw = tuple(np.asarray(raw[off + 6 + j]) for j in range(5))
-            win_cycles = telemetry_window_cycles(cfg)
+        with _span("sim.flight", cat="sim", specs=s):
+            tel = tuple(np.asarray(raw[off + j]) for j in range(6))
+            if cfg.telemetry_windows:
+                telw = tuple(np.asarray(raw[off + 6 + j])
+                             for j in range(5))
+                win_cycles = telemetry_window_cycles(cfg)
+        # the flight recorder's copies to the host (repro.obs.metrics)
+        metrics.inc("flight.calls")
+        metrics.inc("flight.d2h_bytes",
+                    sum(x.nbytes for x in tel + (telw or ())))
     out = []
     for i, spec in enumerate(specs):
         norm = spec.n * meas

@@ -25,7 +25,14 @@ pipeline per function (`jit.trace_s:<fun>`, `jit.lower_s:<fun>`,
 routing builds (`routing.build_s`).  They record only when a function
 compiles or a routing misses its cache, never on a warm call, and are
 read through `metrics.snapshot()`: which runner compiled, and how long
-its trace, lowering and compile (or cache load) took.
+its trace, lowering and compile (or cache load) took.  With the flight
+recorder on, `flight.calls` and `flight.d2h_bytes` count its copies to
+the host.
+
+`phase_maps()` (`obs.phases`) names, for each compiled instruction of
+every runner called while span tracing or profiling was on, the step
+phase it belongs to, so a device trace's ops (`fusion.264`) can be read
+by phase.
 """
 from .trace import (Span, clear_trace, disable_tracing, enable_tracing,  # noqa
                     get_spans, save_chrome_trace, span_summary, trace,
@@ -39,5 +46,7 @@ from .profile import (ProfileRegistry, clear_profiles, disable_profiling,  # noq
                       enable_profiling, get_profiles, profiling_enabled)
 from .bench import (BENCH_SCHEMA_VERSION, bench_doc, compare,  # noqa
                     load_bench, write_bench)
+from .phases import (OUTSIDE, PHASES, RunnerPhases, hlo_phases,  # noqa
+                     phase_maps)
 
 install_compile_listeners()

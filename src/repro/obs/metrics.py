@@ -39,9 +39,14 @@ or off, and only when work is built — never on a warm call:
     `jit.cache_misses` count persistent-cache hits and writes;
   * **routing builds** (`repro.core.routing.routing_for` on a cache
     miss): `routing.build_s`.
+
+With the flight recorder on, `run_batch` counts each call that copies
+its counters to the host (`flight.calls`) and the bytes copied
+(`flight.d2h_bytes`).
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import threading
@@ -219,10 +224,26 @@ _CACHE_EVENTS = {
     "/jax/compilation_cache/cache_misses": "jit.cache_misses",
 }
 _listeners_installed = False
+_muted = threading.local()
+
+
+@contextlib.contextmanager
+def compile_events_muted():
+    """Record no compile-pipeline event of this thread inside the block:
+    for lowering an already compiled function again to read its program
+    (`repro.obs.phases`), which builds nothing and is no set-up."""
+    was = getattr(_muted, "on", False)
+    _muted.on = True
+    try:
+        yield
+    finally:
+        _muted.on = was
 
 
 def _on_compile_duration(event: str, secs: float, *, fun_name: str = "?",
                          **_kw) -> None:
+    if getattr(_muted, "on", False):
+        return
     stage = _COMPILE_STAGES.get(event)
     if stage is not None:
         metrics.observe(f"{stage}:{fun_name}", secs)
@@ -231,6 +252,8 @@ def _on_compile_duration(event: str, secs: float, *, fun_name: str = "?",
 
 
 def _on_compile_event(event: str, **_kw) -> None:
+    if getattr(_muted, "on", False):
+        return
     key = _CACHE_EVENTS.get(event)
     if key is not None:
         metrics.inc(key)

@@ -9,16 +9,24 @@ function of the *padded* shape, not of any individual topology, so the
 profile answers "what does this PadShape cost to run", which is exactly
 the denominator the pad-waste investigation divides live work by.
 
+While profiling or span tracing (`obs.trace`) is on, `run_batch` also
+notes each runner it calls under the same key: the runner (weakly) and
+the abstract arguments of its call, from which `obs.phases` builds the
+runner's phase map when asked.  A note costs no lowering or compile.
+
 Design constraints, matching `obs.trace`:
 
   * **off is free**: profiling is disabled by default and the hot-path
     check is one attribute read; nothing is lowered or compiled unless
     a caller opted in;
-  * **never in timed regions**: `lower().compile()` does NOT share the
-    jit call cache (verified on jax 0.4.37: the AOT compile leaves
-    `_cache_size()` at 0), so a capture costs a full second compile.
-    Benchmarks therefore profile in a separate untimed pass — the
-    registry exists so they only pay that once per executable;
+  * **never in timed regions**: on jax 0.9, `lower().compile()` after
+    the runner's first call finds the traced, lowered and compiled
+    program in the jit's caches (about a millisecond, no retrace or
+    compile; `repro.obs.phases` relies on the same), but a capture
+    before the first call compiles the runner, and the cost and memory
+    analyses take host time of their own.  Benchmarks therefore profile
+    in a separate untimed pass — the registry keeps one profile per
+    executable;
   * **robust to backend gaps**: `cost_analysis`/`memory_analysis` are
     best-effort across backends; missing fields record as None rather
     than raising mid-experiment.
@@ -27,11 +35,12 @@ from __future__ import annotations
 
 import threading
 import time
+import weakref
 
 __all__ = [
     "ProfileRegistry", "PROFILER", "profiling_enabled",
     "enable_profiling", "disable_profiling", "clear_profiles",
-    "get_profiles", "record_runner_profile",
+    "get_profiles", "noted_runners", "record_runner",
 ]
 
 
@@ -70,6 +79,7 @@ class ProfileRegistry:
     def __init__(self):
         self._lock = threading.Lock()
         self._profiles: dict = {}
+        self._noted: dict = {}
         self._enabled = False
 
     # ---- lifecycle -----------------------------------------------------
@@ -86,6 +96,29 @@ class ProfileRegistry:
     def clear(self) -> None:
         with self._lock:
             self._profiles = {}
+            self._noted = {}
+
+    # ---- runners noted for their phase maps ----------------------------
+    def note(self, key: tuple, runner, args, **info) -> None:
+        """Keep `runner` (weakly) and the abstract arguments of a call,
+        once per key; `info` rides along (`obs.phases` reads it)."""
+        with self._lock:
+            if key in self._noted:
+                return
+        import jax
+        entry = dict(info, runner=weakref.ref(runner), args=jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args))
+        with self._lock:
+            self._noted.setdefault(key, entry)
+
+    def noted(self) -> list[dict]:
+        """The notes whose runner is still alive; a runner the runner
+        cache let go of drops out."""
+        with self._lock:
+            for key in [k for k, e in self._noted.items()
+                        if e["runner"]() is None]:
+                del self._noted[key]
+            return list(self._noted.values())
 
     # ---- capture -------------------------------------------------------
     def capture(self, key: tuple, runner, args) -> dict:
@@ -148,15 +181,21 @@ def get_profiles() -> list[dict]:
     return PROFILER.profiles()
 
 
-def record_runner_profile(shape, cfg, alloc_impl: str, kmax: int,
-                          runner, args) -> dict:
-    """Profile a batched runner under its runner-cache key.
+def noted_runners() -> list[dict]:
+    return PROFILER.noted()
 
-    Called by `run_batch` when profiling is enabled; the key mirrors
-    `get_batch_runner` so one profile per compiled executable, however
-    many topologies share it.
+
+def record_runner(shape, cfg, alloc_impl: str, kmax: int,
+                  runner, args) -> dict | None:
+    """Note a batched runner under its runner-cache key, and profile it
+    when profiling is enabled (returns the profile, else None).
+
+    Called by `run_batch` while profiling or span tracing is on; the key
+    mirrors `get_batch_runner` so one note and one profile per compiled
+    executable, however many topologies share it.
     """
     import jax
     key = (shape.n, shape.p, shape.c, shape.d, cfg, alloc_impl, kmax,
            jax.default_backend())
-    return PROFILER.capture(key, runner, args)
+    PROFILER.note(key, runner, args, shape=str(shape), cfg=cfg, kmax=kmax)
+    return PROFILER.capture(key, runner, args) if PROFILER.enabled else None

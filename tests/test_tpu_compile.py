@@ -83,3 +83,35 @@ def test_batched_runner_compiles_with_kernel(one_chip, monkeypatch):
         jax.ShapeDtypeStruct((len(specs), 8), jnp.float32,
                              sharding=one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flight_runner_phase_map_for_v5e(one_chip, monkeypatch):
+    """Compiled for v5e, the flight recorder's scatters become fusions
+    that carry no `op_name` of their own; the phase map still puts them
+    in `step_flight`, by the instructions inside them, and finds every
+    other phase and the kernel."""
+    from repro.obs.phases import PHASES, _parse, hlo_phases
+    monkeypatch.setattr(netstep_ops, "_interpret", lambda: False)
+    r = build_routing(T.build("folded_hexa_torus", 16))
+    batch, shape = stack_specs([sim.make_spec(r, TR.uniform(r.topo))])
+    cfg = sim.SimConfig(cycles=60, warmup=20, telemetry=True,
+                        telemetry_windows=4)
+    runner = sim._make_batch_runner(shape.n, shape.p, shape.c, shape.d,
+                                    cfg, "pallas")
+    as_sds = lambda x: jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype,
+                                            sharding=one_chip)
+    text = runner.lower(
+        jax.tree.map(as_sds, batch),
+        jax.ShapeDtypeStruct((1, 8), jnp.float32,
+                             sharding=one_chip)).compile().as_text()
+    phases, _ = hlo_phases(text)
+    comps, _ = _parse(text)
+    bare = [ins for comp in comps.values() for ins in comp
+            if ins.name in phases and ins.opcode == "fusion"
+            and ins.op_name is None and phases[ins.name] == "step_flight"
+            and any(j.opcode == "scatter" for a, c in ins.called
+                    if a == "calls" for j in comps[c])]
+    assert bare
+    assert set(PHASES) <= set(phases.values())
+    kernels = [n for n, p in phases.items() if n.startswith("netstep")]
+    assert kernels and {phases[k] for k in kernels} == {"step_alloc"}
