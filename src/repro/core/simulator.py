@@ -348,7 +348,23 @@ def _bits_to_unit(bits):
 # route lookup + two-phase separable allocation
 # =====================================================================
 
-def _route_lookup(table, cred_pad, head_dst, cnt, n: int, p: int, v: int):
+def _select_last(x, idx):
+    """`x[..., idx]` along the last axis, with `idx` (shaped like
+    `x.shape[:-1]`, or broadcast against it) holding one in-range slot
+    per element.
+
+    The step's indexed axes are short and static (B buffer slots, V VCs,
+    P+1 ports), so a one-hot compare-and-select over the axis is a few
+    elementwise ops, where an element gather reads one element per
+    index.  Exactly one slot matches, so the sum is the picked value,
+    bitwise.
+    """
+    hit = idx[..., None] == jnp.arange(x.shape[-1])
+    return jnp.sum(jnp.where(hit, x, jnp.zeros((), x.dtype)), axis=-1,
+                   dtype=x.dtype)
+
+
+def _route_lookup(table, cred_pad, head_dst, cnt, n: int, p: int):
     """Table lookup + credit check for every (node, in-port, VC) head flit.
 
     Returns op_slot [N, PI, V] int32 (requested output slot, ejection = P,
@@ -361,7 +377,6 @@ def _route_lookup(table, cred_pad, head_dst, cnt, n: int, p: int, v: int):
     PI = p + 1
     node_idx = jnp.arange(n)[:, None, None]
     port_idx = jnp.arange(PI)[None, :, None]
-    vcs = jnp.arange(v)[None, None, :]
 
     valid = cnt > 0
     dst = jnp.where(valid, head_dst, 0)
@@ -369,7 +384,9 @@ def _route_lookup(table, cred_pad, head_dst, cnt, n: int, p: int, v: int):
     op = jnp.where(valid, op, -3)
     is_eject = op == Routing.EJECT
     op_slot = jnp.where(is_eject, p, op)           # [N, PI, V]
-    have_credit = cred_pad[node_idx, jnp.clip(op_slot, 0, p), vcs] > 0
+    # credit of VC v at output slot op_slot: select along the port axis
+    cred_vp = jnp.swapaxes(cred_pad, 1, 2)[:, None]        # [N, 1, V, P+1]
+    have_credit = _select_last(cred_vp, jnp.clip(op_slot, 0, p)) > 0
     eligible = valid & (op_slot >= 0) & (have_credit | is_eject)
     starved = valid & (op_slot >= 0) & ~is_eject & ~have_credit
     return op_slot, eligible, starved
@@ -404,7 +421,8 @@ def _route_lookup_adaptive(table, prod, cred_pad, head_dst, cnt,
     op = jnp.where(valid, op, -3)
     is_eject = op == Routing.EJECT
     esc_slot = jnp.where(is_eject, p, op)
-    esc_credit = cred_pad[node_idx, jnp.clip(esc_slot, 0, p), 0] > 0
+    esc_credit = _select_last(cred_pad[:, None, None, :, 0],
+                              jnp.clip(esc_slot, 0, p)) > 0
 
     # adaptive candidates: productive ports weighted by the summed
     # downstream adaptive-class credit (argmax = first-max tie-break)
@@ -415,7 +433,9 @@ def _route_lookup_adaptive(table, prod, cred_pad, head_dst, cnt,
     ad_port = jnp.argmax(score, axis=3).astype(jnp.int32)  # [N, PI, V]
     ad_ok = jnp.max(score, axis=3) > 0
     # downstream adaptive VC with the most credit at the chosen port
-    pcred = cred_pad[node_idx, jnp.clip(ad_port, 0, p - 1), 1:]
+    pcred = _select_last(                          # [N, PI, V, V-1]
+        jnp.swapaxes(cred_pad[:, :, 1:], 1, 2)[:, None, None],
+        jnp.clip(ad_port, 0, p - 1)[..., None])
     dvc_ad = 1 + jnp.argmax(pcred, axis=3).astype(jnp.int32)
 
     use_ad = valid & ~is_eject & ad_ok
@@ -488,7 +508,7 @@ def router_phase(table, out_ch_pad_credits, head_dst, cnt, rr,
     port_wins) like the seed implementation.
     """
     op_slot, eligible, _ = _route_lookup(table, out_ch_pad_credits,
-                                         head_dst, cnt, n, p, v)
+                                         head_dst, cnt, n, p)
     win_mask, vc_choice, out_req = _alloc_jnp(op_slot, eligible, rr, rr)
     return win_mask, out_req, vc_choice, jnp.any(win_mask, axis=2)
 
@@ -648,10 +668,9 @@ def _make_batch_runner(nm: int, pm: int, cm: int, dm: int,
         with jax.named_scope("step_route"):
             cnt_obs = cnt            # occupancy snapshot (flight recorder):
             #                          post-arrival, post-injection, pre-pop
-            head_dst = jnp.take_along_axis(
-                buf_dst, state.head[..., None], axis=3)[..., 0]
-            head_t = jnp.take_along_axis(
-                buf_t, state.head[..., None], axis=3)[..., 0]
+            # slot B is the sacrificial write sink: head < B always
+            head_dst = _select_last(buf_dst[..., :B], state.head)
+            head_t = _select_last(buf_t[..., :B], state.head)
             cred_pad = jnp.concatenate(
                 [credits, jnp.full((N, 1, V), INF, jnp.int32)], axis=1)
             if adaptive:
@@ -659,7 +678,7 @@ def _make_batch_runner(nm: int, pm: int, cm: int, dm: int,
                     a.table, a.prod, cred_pad, head_dst, cnt, N, P, V)
             else:
                 op_slot, eligible, starved = _route_lookup(
-                    a.table, cred_pad, head_dst, cnt, N, P, V)
+                    a.table, cred_pad, head_dst, cnt, N, P)
         with jax.named_scope("step_alloc"):
             rr_vc = state.rr % V
             rr_port = state.rr % a.pi
@@ -677,9 +696,9 @@ def _make_batch_runner(nm: int, pm: int, cm: int, dm: int,
             # lane) stays on wvc while the link VC tag and the downstream
             # credit decrement move to w_dvc.
             wvc = vc_choice
-            w_dvc = dvc[nn, pp, wvc] if adaptive else wvc
-            w_dst = head_dst[nn, pp, wvc]
-            w_t = head_t[nn, pp, wvc]
+            w_dvc = _select_last(dvc, wvc) if adaptive else wvc
+            w_dst = _select_last(head_dst, wvc)
+            w_t = _select_last(head_t, wvc)
             head = (state.head.at[nn, pp, wvc]
                     .add(port_wins.astype(jnp.int32))) % B
             cnt = cnt.at[nn, pp, wvc].add(-port_wins.astype(jnp.int32))

@@ -95,6 +95,9 @@ GOLDEN = {
     'telemetry:fht16': {'delivered': [163, 654, 1950], 'offered_n': [161, 653, 1948], 'accepted_n': [161, 653, 1935], 'lat_sum': [2240, 9071, 32384]},
     'telemetry:fht16:tel': {'link_busy': 4787, 'link_stall': 929, 'inj_node': 2749, 'eject_node': 2767},
     'faulted:mesh16_k2': {'delivered': [164, 666, 964], 'offered_n': [161, 653, 1948], 'accepted_n': [161, 653, 990], 'lat_sum': [3695, 16056, 66809]},
+    # captured from the step that still read head flits, credits and
+    # winners by element gathers, before the one-hot selects replaced them
+    'adaptive:fht16_drift': {'delivered': [156, 633, 730], 'offered_n': [155, 634, 1874], 'accepted_n': [155, 624, 833], 'lat_sum': [2163, 26149, 55315], 'delivered_ph': [0, 73, 83, 0, 314, 319, 0, 297, 433]},
 }
 
 
@@ -153,6 +156,65 @@ def test_static_pin_faulted():
     spec = make_spec(rdeg, fs.mask_traffic(TR.uniform(mesh.topo)))
     res = run_batch([spec], _PIN_RATES[None, :], _PIN_CFG)[0]
     _pin_check("faulted:mesh16_k2", res)
+
+
+def test_adaptive_pin_workload():
+    """The adaptive route lookup (escape credit, candidate credit and
+    the downstream VC of each winner) under a drifting hotspot."""
+    import repro.workloads as W
+    from repro.core.simulator import make_spec, run_batch
+    fht16 = build_routing(T.build("folded_hexa_torus", 16))
+    sched = W.hotspot_drift(fht16.topo, n_phases=3, dwell=100,
+                            seed=1).fit(_PIN_CFG.cycles).compile()
+    spec = make_spec(fht16, TR.uniform(fht16.topo))
+    res = run_batch([spec], _PIN_RATES[None, :],
+                    _PIN_CFG._replace(routing="adaptive"),
+                    schedules=[sched])[0]
+    _pin_check("adaptive:fht16_drift", res, _PIN_RAW + ("delivered_ph",))
+
+
+# ---------------------------------------------------------------------
+# the step's one-hot reads
+# ---------------------------------------------------------------------
+
+@pytest.mark.parametrize("case", ["head", "credit", "winner"])
+def test_select_last_matches_gathers(case):
+    """`_select_last` reads what `take_along_axis` and fancy indexing
+    read, bitwise, at the step's three kinds of index: a head slot of
+    the B live buffer slots, a clipped output slot of the credit table
+    (its last column INF), and the winning VC."""
+    import jax.numpy as jnp
+    from repro.core.simulator import INF, _select_last
+    rng = np.random.default_rng(7)
+    n, pi, v, b = 5, 4, 3, 4
+    big = np.iinfo(np.int32)
+    if case == "head":
+        buf = rng.integers(big.min, big.max, (n, pi, v, b + 1), np.int32)
+        head = rng.integers(0, b, (n, pi, v), np.int32)
+        head[0], head[1] = 0, b - 1
+        got = _select_last(jnp.asarray(buf)[..., :b], jnp.asarray(head))
+        want = np.take_along_axis(buf, head[..., None], axis=3)[..., 0]
+    elif case == "credit":
+        p = pi - 1
+        cred = rng.integers(-3, 5, (n, p, v), np.int32)
+        cred_pad = np.concatenate(
+            [cred, np.full((n, 1, v), int(INF), np.int32)], axis=1)
+        op_slot = rng.integers(-3, pi, (n, pi, v), np.int32)
+        op_slot[0], op_slot[1] = p, -1
+        slot = np.clip(op_slot, 0, p)
+        got = _select_last(jnp.swapaxes(jnp.asarray(cred_pad), 1, 2)[:, None],
+                           jnp.asarray(slot))
+        want = cred_pad[np.arange(n)[:, None, None], slot,
+                        np.arange(v)[None, None, :]]
+        assert (want == int(INF)).any() and (slot == 0).any()
+    else:
+        head_dst = rng.integers(big.min, big.max, (n, pi, v), np.int32)
+        wvc = rng.integers(0, v, (n, pi), np.int32)
+        wvc[0], wvc[1] = 0, v - 1
+        got = _select_last(jnp.asarray(head_dst), jnp.asarray(wvc))
+        want = head_dst[np.arange(n)[:, None], np.arange(pi)[None, :], wvc]
+    assert got.dtype == jnp.int32
+    assert np.array_equal(np.asarray(got), want)
 
 
 # ---------------------------------------------------------------------

@@ -115,3 +115,39 @@ def test_flight_runner_phase_map_for_v5e(one_chip, monkeypatch):
     assert set(PHASES) <= set(phases.values())
     kernels = [n for n, p in phases.items() if n.startswith("netstep")]
     assert kernels and {phases[k] for k in kernels} == {"step_alloc"}
+
+
+def test_static_runner_route_reads_by_select(one_chip, monkeypatch):
+    """Compiled for v5e, the static step's route lookup holds one gather:
+    the s16 routing-table read.  The head flit, its credit and the
+    winner's flit are one-hot selects, which compile to no gather; every
+    phase of the telemetry-off step is still found."""
+    import re
+    from repro.obs.phases import PHASES, _parse, hlo_phases
+    monkeypatch.setattr(netstep_ops, "_interpret", lambda: False)
+    r = build_routing(T.build("folded_hexa_torus", 16))
+    batch, shape = stack_specs([sim.make_spec(r, TR.uniform(r.topo))])
+    cfg = sim.SimConfig(cycles=60, warmup=20)
+    runner = sim._make_batch_runner(shape.n, shape.p, shape.c, shape.d,
+                                    cfg, "pallas")
+    as_sds = lambda x: jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype,
+                                            sharding=one_chip)
+    text = runner.lower(
+        jax.tree.map(as_sds, batch),
+        jax.ShapeDtypeStruct((1, 8), jnp.float32,
+                             sharding=one_chip)).compile().as_text()
+    phases, _ = hlo_phases(text)
+    comps, _ = _parse(text)
+
+    def gathers(ins):
+        return ins.opcode == "gather" or any(
+            j.opcode == "gather" for a, c in ins.called if a == "calls"
+            for j in comps[c])
+
+    route = [ins.name for comp in comps.values() for ins in comp
+             if phases.get(ins.name) == "step_route" and gathers(ins)]
+    assert len(route) == 1, route
+    result = re.search(rf"^\s*(?:ROOT\s+)?%?{re.escape(route[0])} = (\S+)",
+                       text, re.M)
+    assert result.group(1).startswith("s16["), result.group(0)
+    assert set(PHASES) - {"step_flight"} <= set(phases.values())
