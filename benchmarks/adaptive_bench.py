@@ -29,7 +29,6 @@ import numpy as np
 
 import repro.experiments as X
 import repro.workloads as W
-from repro.configs import get_config
 from repro.core.simulator import SimConfig
 from repro.compile_cache import use_compile_cache
 
@@ -64,7 +63,7 @@ def traffic_suite(names, arch: str = "qwen3_1_7b") -> list:
             out.append(W.Workload("bursty", partial(
                 W.bursty_uniform, on=20, off=60)))
         elif w == "mixed_tenant":
-            out.append(W.mixed_tenant(get_config(arch)))
+            out.append(W.mixed_tenant(W.arch_sizes(arch)))
         else:
             raise KeyError(f"unknown workload {w!r}")
     return out

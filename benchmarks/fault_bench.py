@@ -33,7 +33,6 @@ import numpy as np
 
 import repro.experiments as X
 import repro.workloads as W
-from repro.configs import get_config
 from repro.core import topology as T
 from repro.core.simulator import SimConfig, zero_load_latency
 from repro.faults import FaultError, sample_faults
@@ -104,7 +103,7 @@ def bench_faults(params: dict, arch: str = "qwen3_1_7b") -> list[dict]:
                    tags=(("k_failed", k), ("suite", "static")))
         for name, substrate, k, fs in cells]
 
-    mixed = W.mixed_tenant(get_config(arch), serve_frac=0.3)
+    mixed = W.mixed_tenant(W.arch_sizes(arch), serve_frac=0.3)
     mixed_cells = fault_grid(params["mixed_names"], n,
                              params["substrates"], params["mixed_ks"])
     scenarios += [

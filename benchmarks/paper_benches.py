@@ -188,23 +188,6 @@ def collectives_bridge(sizes=None):
     return mesh["allreduce_ms"] / fht["allreduce_ms"]
 
 
-def roofline_summary(sizes=None):
-    """Framework roofline over the dry-run artifacts (if present)."""
-    import glob
-    from .roofline import analyze
-    for d in ("results/dryrun_opt", "results/dryrun"):
-        if glob.glob(os.path.join(d, "*.json")):
-            rows = [r for r in analyze(d) if r.get("ok")]
-            if not rows:
-                continue
-            best = max(rows, key=lambda r: r["roofline_fraction"])
-            n_mem = sum(r["dominant"] == "memory" for r in rows)
-            return (f"{len(rows)} cells ({d}); "
-                    f"{n_mem} memory-bound; best fraction "
-                    f"{best['roofline_fraction']:.3f} ({best['tag']})")
-    return "no dry-run artifacts (run repro.launch.dryrun first)"
-
-
 def sweep_speedup(sizes=None):
     """Batched-vs-looped simulator sweep wall-clock (DESIGN.md §6/§7)."""
     from .sweep_bench import bench_speedup
@@ -225,6 +208,5 @@ BENCHES = {
     "fig8_patterns": fig8_patterns,
     "fig10_traces": fig10_traces,
     "collectives_bridge": collectives_bridge,
-    "roofline_summary": roofline_summary,
     "sweep_speedup": sweep_speedup,
 }

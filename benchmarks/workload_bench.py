@@ -30,7 +30,6 @@ import numpy as np
 
 import repro.experiments as X
 import repro.workloads as W
-from repro.configs import get_config
 from repro.core.simulator import SimConfig
 from repro.compile_cache import use_compile_cache
 
@@ -51,7 +50,7 @@ FULL = dict(names="ALL", n=64, n_rates=6, cycles=2000, warmup=700,
 
 
 def workload_suite(arch: str = "qwen3_1_7b") -> list[W.Workload]:
-    cfg = get_config(arch)
+    cfg = W.arch_sizes(arch)
     return [
         W.Workload(f"collective:{cfg.name}",
                    partial(W.collective_workload, cfg)),

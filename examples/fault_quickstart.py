@@ -14,7 +14,6 @@ import os
 import repro.experiments as X
 import repro.faults as F
 import repro.workloads as W
-from repro.configs import get_config
 from repro.core.simulator import SimConfig
 from repro.core.topology import build
 
@@ -43,7 +42,7 @@ def main():
 
     print("\n=== mixed tenant (train collectives + 30% serving) "
           "through the same masks ===")
-    mixed = W.mixed_tenant(get_config("qwen3_1_7b"), serve_frac=0.3)
+    mixed = W.mixed_tenant(W.arch_sizes("qwen3_1_7b"), serve_frac=0.3)
     topo = build("folded_hexa_torus", 36)
     scenarios = [X.Scenario("folded_hexa_torus", 36, traffic=mixed,
                             faults=F.sample_faults(topo, k, "random",

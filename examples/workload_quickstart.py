@@ -17,12 +17,11 @@ import numpy as np
 
 import repro.experiments as X
 import repro.workloads as W
-from repro.configs import get_config
 from repro.core.simulator import SimConfig
 
 
 def main():
-    cfg = get_config("qwen3_1_7b")
+    cfg = W.arch_sizes("qwen3_1_7b")
     workloads = [
         W.Workload(f"collective:{cfg.name}",
                    partial(W.collective_workload, cfg)),

@@ -1,7 +1,7 @@
 """repro.core — the paper's contribution: FoldedHexaTorus and the ICI
 topology evaluation pipeline (topologies, routing, simulator, cost models,
-and the topology-aware collective model that plugs into the training
-framework's roofline analyzer)."""
+the topology-aware collective cost model, and the mesh-to-chiplet flow
+mapping of the collective workloads)."""
 from .topology import Topology, build, GENERATORS, N_CONSTRAINTS, \
     make_topology, register_topology, unregister_topology, \
     validate_edges, valid_n, nearest_valid_n  # noqa

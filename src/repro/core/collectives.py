@@ -1,10 +1,11 @@
-"""Topology-aware collective cost model — the paper -> framework bridge.
+"""Topology-aware collective cost model — the paper -> workload bridge.
 
 On a chiplet-based accelerator, the ICI topology determines the effective
 bandwidth available to the collectives a sharded training step issues.
 This module converts the paper's saturation-throughput results into
-per-collective time estimates, so the roofline analyzer can report the
-collective term *under each ICI topology* (`--ici-topology ...`).
+per-collective time estimates under each ICI topology, and maps a
+logical mesh's collectives onto chiplet flows (`mesh_axis_groups`,
+`collective_flow`) for `workloads.collective`.
 
 Model: the effective all-to-all bandwidth per chiplet is the topology's
 absolute saturation throughput T_a under uniform traffic (this bakes in

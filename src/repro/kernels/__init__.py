@@ -1,17 +1,11 @@
-"""Pallas TPU kernels for the framework's compute hot spots.
+"""Pallas TPU kernel for the simulator's hot loop.
 
-Each kernel directory has:
+The kernel directory has:
     <name>.py — pl.pallas_call + explicit BlockSpec VMEM tiling
     ops.py    — jit'd public wrapper (interpret=True on CPU)
-    ref.py    — pure-jnp oracle used by the allclose test sweeps
+    ref.py    — pure-jnp oracle used by the bitwise test sweeps
 
-Kernels:
-    flash_attention — blockwise causal/sliding-window attention with an
-        online softmax (the quadratic-memory hot spot of every attention
-        arch at train_4k/prefill_32k).
-    ssd_scan — Mamba2 SSD chunked scan; the sequential inter-chunk
-        recurrence is carried across the TPU grid's sequential minor axis
-        in a VMEM scratch accumulator.
-    netstep — the paper-specific hot loop: the ICI simulator's two-phase
-        separable switch allocation, tiled over router blocks.
+Kernel:
+    netstep — the ICI simulator's two-phase separable switch
+        allocation, tiled over router blocks.
 """

@@ -79,10 +79,12 @@ def mixed_tenant_workload(config, topo: Topology, *,
                           **collective_kw) -> Schedule:
     """Training collectives of `config` + a serving tenant on `topo`.
 
-    The serving tenant offers `serve_frac` of every phase's load as the
-    named static pattern (requests and KV-cache reads spread over the
-    package); the remaining (1 - serve_frac) is the training step's
-    phase-varying collective traffic."""
+    `config` is an architecture's sizes (`arch_sizes("qwen3-1.7b")`, or
+    any object with `ArchSizes`' fields).  The serving tenant offers
+    `serve_frac` of every phase's load as the named static pattern
+    (requests and KV-cache reads spread over the package); the remaining
+    (1 - serve_frac) is the training step's phase-varying collective
+    traffic."""
     train = collective_workload(config, topo, **collective_kw)
     bg = TR.PATTERNS[serve_pattern](topo)
     return superimpose(

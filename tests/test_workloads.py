@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 
 import repro.workloads as W
-from repro.configs import get_config
 from repro.core import topology as T, traffic as TR
 from repro.core.collectives import (collective_flow, mesh_axis_groups,
                                     mesh_coords)
@@ -18,6 +17,7 @@ from repro.core.routing import build_routing
 from repro.core.simulator import (SimConfig, make_sched_spec, make_spec,
                                   phase_measured_cycles, run_batch)
 from repro.sweep.engine import SweepCase, SweepEngine
+from repro.workloads.collective import step_collective_ops
 
 CFG = SimConfig(cycles=300, warmup=100)
 RAW = ("delivered", "offered_n", "accepted_n", "lat_sum")
@@ -211,20 +211,141 @@ def test_collective_flow_conserves_payload():
         collective_flow(8, "broadcastish", groups, 1.0)
 
 
-def test_collective_workload_phases(fht16):
-    cfg = get_config("qwen3_1_7b")
+#: phase -> (kind, mesh axis) of the ops `step_collective_ops` issues
+PHASE_OPS = {
+    "fsdp_gather": ("all_gather", "data"),
+    "fwd_tp": ("all_reduce", "model"),
+    "moe_a2a": ("all_to_all", "model"),
+    "bwd_tp": ("all_reduce", "model"),
+    "grad_reduce": ("reduce_scatter", "data"),
+}
+
+#: pinned op lists, per architecture and "data x model" mesh: each
+#: op's (phase, bytes per chip) in order, at seq_len 2048 and 4
+#: sequences per data shard (`collective_workload`'s sizing)
+COLLECTIVE_OPS_PINNED = {
+    "qwen3_1_7b": {
+        "8x8": (("fsdp_gather", 507904000), ("fwd_tp", 1879048192),
+                ("bwd_tp", 1879048192), ("grad_reduce", 507904000)),
+        "16x4": (("fsdp_gather", 1015808000), ("fwd_tp", 1879048192),
+                 ("bwd_tp", 1879048192), ("grad_reduce", 1015808000)),
+        "64x1": (("fsdp_gather", 4063232000), ("grad_reduce", 4063232000)),
+    },
+    "gemma3_1b": {
+        "8x8": (("fsdp_gather", 325435392), ("fwd_tp", 981467136),
+                ("bwd_tp", 981467136), ("grad_reduce", 325435392)),
+        "16x4": (("fsdp_gather", 650870784), ("fwd_tp", 981467136),
+                 ("bwd_tp", 981467136), ("grad_reduce", 650870784)),
+        "64x1": (("fsdp_gather", 2603483136), ("grad_reduce", 2603483136)),
+    },
+    "starcoder2_3b": {
+        "8x8": (("fsdp_gather", 1078198272), ("fwd_tp", 3019898880),
+                ("bwd_tp", 3019898880), ("grad_reduce", 1078198272)),
+        "16x4": (("fsdp_gather", 2156396544), ("fwd_tp", 3019898880),
+                 ("bwd_tp", 3019898880), ("grad_reduce", 2156396544)),
+        "64x1": (("fsdp_gather", 8625586176), ("grad_reduce", 8625586176)),
+    },
+    "minicpm3_4b": {
+        "8x8": (("fsdp_gather", 1262192640), ("fwd_tp", 5200936960),
+                ("bwd_tp", 5200936960), ("grad_reduce", 1262192640)),
+        "16x4": (("fsdp_gather", 2524385280), ("fwd_tp", 5200936960),
+                 ("bwd_tp", 5200936960), ("grad_reduce", 2524385280)),
+        "64x1": (("fsdp_gather", 10097541120), ("grad_reduce", 10097541120)),
+    },
+    "seamless_m4t_medium": {
+        "8x8": (("fsdp_gather", 181509120), ("fwd_tp", 402653184),
+                ("bwd_tp", 402653184), ("grad_reduce", 181509120)),
+        "16x4": (("fsdp_gather", 363018240), ("fwd_tp", 402653184),
+                 ("bwd_tp", 402653184), ("grad_reduce", 363018240)),
+        "64x1": (("fsdp_gather", 1452072960), ("grad_reduce", 1452072960)),
+    },
+    "qwen3_moe_235b_a22b": {
+        "8x8": (("fsdp_gather", 58760888320), ("fwd_tp", 12616466432),
+                ("moe_a2a", 50465865728), ("bwd_tp", 12616466432),
+                ("grad_reduce", 58760888320)),
+        "16x4": (("fsdp_gather", 117521776640), ("fwd_tp", 12616466432),
+                 ("moe_a2a", 50465865728), ("bwd_tp", 12616466432),
+                 ("grad_reduce", 117521776640)),
+        "64x1": (("fsdp_gather", 470087106560), ("grad_reduce", 470087106560)),
+    },
+    "grok_1_314b": {
+        "8x8": (("fsdp_gather", 79121350656), ("fwd_tp", 12884901888),
+                ("moe_a2a", 12884901888), ("bwd_tp", 12884901888),
+                ("grad_reduce", 79121350656)),
+        "16x4": (("fsdp_gather", 158242701312), ("fwd_tp", 12884901888),
+                 ("moe_a2a", 12884901888), ("bwd_tp", 12884901888),
+                 ("grad_reduce", 158242701312)),
+        "64x1": (("fsdp_gather", 632970805248), ("grad_reduce", 632970805248)),
+    },
+    "chameleon_34b": {
+        "8x8": (("fsdp_gather", 8573157376), ("fwd_tp", 12884901888),
+                ("bwd_tp", 12884901888), ("grad_reduce", 8573157376)),
+        "16x4": (("fsdp_gather", 17146314752), ("fwd_tp", 12884901888),
+                 ("bwd_tp", 12884901888), ("grad_reduce", 17146314752)),
+        "64x1": (("fsdp_gather", 68585259008), ("grad_reduce", 68585259008)),
+    },
+    "jamba_v0_1_52b": {
+        "8x8": (("fsdp_gather", 12448694272), ("fwd_tp", 4294967296),
+                ("moe_a2a", 2147483648), ("bwd_tp", 4294967296),
+                ("grad_reduce", 12448694272)),
+        "16x4": (("fsdp_gather", 24897388544), ("fwd_tp", 4294967296),
+                 ("moe_a2a", 2147483648), ("bwd_tp", 4294967296),
+                 ("grad_reduce", 24897388544)),
+        "64x1": (("fsdp_gather", 99589554176), ("grad_reduce", 99589554176)),
+    },
+    "mamba2_1_3b": {
+        "8x8": (("fsdp_gather", 57778176), ("fwd_tp", 3221225472),
+                ("bwd_tp", 3221225472), ("grad_reduce", 57778176)),
+        "16x4": (("fsdp_gather", 115556352), ("fwd_tp", 3221225472),
+                 ("bwd_tp", 3221225472), ("grad_reduce", 115556352)),
+        "64x1": (("fsdp_gather", 462225408), ("grad_reduce", 462225408)),
+    },
+}
+
+
+@pytest.mark.parametrize("mesh", ["8x8", "16x4", "64x1"])
+@pytest.mark.parametrize("arch", list(COLLECTIVE_OPS_PINNED))
+def test_collective_ops_pinned(arch, mesh):
+    """The collectives of one training step of each published
+    architecture, op for op and byte for byte."""
+    dm, tm = (int(x) for x in mesh.split("x"))
+    cfg = W.arch_sizes(arch)
+    ops = step_collective_ops(cfg, {"data": dm, "model": tm},
+                              seq_len=2048, global_batch=4 * dm)
+    want = [(ph, *PHASE_OPS[ph], b)
+            for ph, b in COLLECTIVE_OPS_PINNED[arch][mesh]]
+    assert [(o.phase, o.kind, o.axis, o.bytes_per_chip) for o in ops] \
+        == want
+    assert all(type(o.bytes_per_chip) is float for o in ops)
+
+
+def test_arch_sizes_lookup():
+    """Published name and canonical key find the same entry."""
+    assert list(W.ARCHS) == list(COLLECTIVE_OPS_PINNED)
+    for key, cfg in W.ARCHS.items():
+        assert W.arch_sizes(key) is cfg
+        assert W.arch_sizes(cfg.name) is cfg
+    assert W.arch_sizes("qwen3-1.7b").d_model == 2048
+    with pytest.raises(KeyError, match="unknown architecture"):
+        W.arch_sizes("qwen4-1b")
+
+
+@pytest.mark.parametrize("arch", list(COLLECTIVE_OPS_PINNED))
+def test_collective_workload_phases(fht16, arch):
+    cfg = W.arch_sizes(arch)
     sched = W.collective_workload(cfg, fht16.topo, step_cycles=800)
     labels = [p.label for p in sched.phases]
-    assert labels == ["fsdp_gather", "fwd_tp", "bwd_tp", "grad_reduce"]
+    # MoE archs add the all-to-all phase
+    moe = ["moe_a2a"] if cfg.n_experts else []
+    assert labels == ["fsdp_gather", "fwd_tp", *moe, "bwd_tp",
+                      "grad_reduce"]
     assert max(p.intensity for p in sched.phases) == 1.0
     for p in sched.phases:
         m = np.asarray(p.traffic)
         assert (m >= 0).all() and np.abs(np.diag(m)).max() == 0
         assert p.duration >= 1
-    # MoE archs add the all-to-all phase
-    moe = W.collective_workload(get_config("qwen3_moe_235b_a22b"),
-                                fht16.topo)
-    assert "moe_a2a" in [p.label for p in moe.phases]
+    if arch != "qwen3_1_7b":
+        return
     # and the whole thing simulates
     spec = make_spec(fht16, sched.mean_traffic())
     res = run_batch([spec], np.array([[0.3]], np.float32), CFG,
